@@ -62,6 +62,8 @@ def benchmark_cli(main, quick_help: str = "smaller workload (CI smoke)",
                     help="also write every cell as structured JSON "
                          "(the CI artifact)")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     print("name,us_per_call,derived")
     main(quick=args.quick, emit_json=args.emit_json)
 

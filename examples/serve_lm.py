@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.configs import SMOKES
 from repro.models import lm
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve import (SamplingParams, ServeConfig, ServeEngine,
                          TelemetryConfig)
 
@@ -56,6 +57,7 @@ def main():
             if args.telemetry else None)
     if args.telemetry and args.no_power:
         ap.error("--telemetry requires power accounting (drop --no-power)")
+    enable_compile_cache()
     cfg = SMOKES[args.arch]
     params = lm.init_model(jax.random.key(0), cfg)
     engine = ServeEngine(params, cfg, ServeConfig(

@@ -2,8 +2,9 @@
 
 Each kernel directory contains ``kernel.py`` (pl.pallas_call + BlockSpec),
 ``ops.py`` (jitted public wrapper) and ``ref.py`` (pure-jnp oracle used by
-the allclose tests). Kernels are validated with ``interpret=True`` on CPU;
-on TPU hardware pass ``interpret=False`` for the Mosaic lowering.
+the allclose tests). ``interpret=None``, every kernel's default, compiles
+with Mosaic on a TPU and runs the Pallas interpreter elsewhere
+(:mod:`repro.kernels.platform`).
 """
 from .bic_encode.ops import bic_encode  # noqa: F401
 from .power_counters.ops import edge_counters  # noqa: F401

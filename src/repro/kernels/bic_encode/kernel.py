@@ -37,6 +37,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bits import segment_width
+from repro.kernels.platform import resolve_interpret
 
 
 def _compose(f, g):
@@ -72,11 +73,12 @@ def _bic_kernel(x_ref, xprev_ref, tx_ref, inv_ref, state_ref, *,
 
 def bic_encode_pallas(x: jax.Array, mask: int,
                       block_t: int = 256, block_l: int = 128,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """Single-segment BIC encode of ``uint16[T, L]`` via the Pallas kernel.
 
     Returns ``(tx: uint16[T, L], inv: bool[T, L])``; bus assumed to idle at 0.
     """
+    interpret = resolve_interpret(interpret)
     x = x.astype(jnp.uint16)
     T, L = x.shape
     width = segment_width(mask)

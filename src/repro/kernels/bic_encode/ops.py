@@ -13,7 +13,7 @@ from .ref import bic_encode_ref
 
 @partial(jax.jit, static_argnames=("mask", "use_pallas", "interpret"))
 def bic_encode(x: jax.Array, mask: int = int(MANT_MASK),
-               use_pallas: bool = True, interpret: bool = True):
+               use_pallas: bool = True, interpret: bool | None = None):
     """Single-segment BIC encode of ``uint16[T, L]``.
 
     Returns ``(tx: uint16[T, L], inv: bool[T, L])``. The default mask is the

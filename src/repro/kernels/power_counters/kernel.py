@@ -12,11 +12,14 @@ The kernel has two in-block algorithms, selected by the static ``algo``
 argument (both bit-exact, differentially tested against each other and
 ``ref.py``):
 
-* ``"parallel"`` -- the TPU form: both sequential recurrences become
-  associative scans (log-depth, fully lane-vectorized, Mosaic-friendly),
-  and the scan count is MENU-SIZE-INDEPENDENT (three per block).
-* ``"scan"`` -- the CPU/interpret form: ONE ``lax.scan`` over the
-  block's cycles computes every counter of every menu entry per step.
+* ``"parallel"`` -- the TPU form, and the one Mosaic compiles: both
+  sequential recurrences become log-step prefix scans (log-depth, fully
+  lane-vectorized, words widened to int32), and the scan count is
+  MENU-SIZE-INDEPENDENT (three per block).
+* ``"scan"`` -- the CPU/interpret form (Mosaic refuses its in-kernel
+  ``lax.scan``, so it is never the compiled default): ONE ``lax.scan``
+  over the block's cycles computes every counter of every menu entry
+  per step.
   A sequential scan is what XLA:CPU compiles best (single fused loop,
   row-sized working set); doing ALL menu entries in that one loop is
   exactly the fused-pass win over the reference's per-menu-entry scans.
@@ -25,7 +28,7 @@ The parallel form's recurrences:
 
 * BIC: inverting a segment flips all of its bits, so the invert decision
   is a composition of per-step boolean functions of the previous state --
-  an ``associative_scan`` over (f(0), f(1)) pairs (the identity proven in
+  a prefix scan over (f(0), f(1)) pairs (the identity proven in
   ``repro.kernels.bic_encode``). Two refinements on top of that kernel:
   (a) the composition ``h(s) = f(s) ? g(1) : g(0)`` is BITWISE, so every
   unique segment's pair rides one bit lane of a packed int32 -- ALL
@@ -35,7 +38,7 @@ The parallel form's recurrences:
   ``w - d`` when it flips.
 * ZVG: the held register value is "last non-zero word so far", i.e. the
   value packed under a running MAX of ``index << 16 | word`` (unset
-  cycles pack to -1) -- an ``associative_scan`` of ``maximum``.
+  cycles pack to -1) -- a prefix scan of ``maximum``.
 
 Cross-block state (held value, previous is-zero bit, the previous
 block's last word, one PACKED invert word per encoded stream) is carried
@@ -65,6 +68,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.bits import MANT_MASK, segment_width
+from repro.kernels.platform import resolve_interpret
 
 from .spec import WORD_BITS, CounterSpec
 
@@ -83,6 +87,30 @@ def _compose_packed(f, g):
     return ((f0 & g1) | (~f0 & g0), (f1 & g1) | (~f1 & g0))
 
 
+def _shift_down(x, k: int, fill: int):
+    """``x`` moved ``k`` rows down its first axis, the top ``k`` rows
+    filled with ``fill``."""
+    pad = jnp.full((k,) + x.shape[1:], fill, x.dtype)
+    return jnp.concatenate([pad, x[:-k]], axis=0)
+
+
+def _prefix_scan(combine, elems: tuple, identity: tuple) -> tuple:
+    """Inclusive prefix scan along axis 0 by log-step doubling.
+
+    ``combine(earlier, later)`` is associative over tuples of equally
+    shaped arrays and ``identity`` is its neutral element, one scalar per
+    array. Built from statically shifted concatenates because Mosaic
+    lowers those, while ``lax.associative_scan``'s strided slices lower
+    to zero-size vectors."""
+    n = elems[0].shape[0]
+    k = 1
+    while k < n:
+        prev = tuple(_shift_down(e, k, i) for e, i in zip(elems, identity))
+        elems = combine(prev, elems)
+        k *= 2
+    return elems
+
+
 def _seg_distances(xo, masks):
     """Per-mask popcounts of an XOR-delta block, memoized across the
     fixed menu masks (0xFFFF and the mantissa field are also counter
@@ -91,8 +119,7 @@ def _seg_distances(xo, masks):
 
     def d(m):
         if m not in cache:
-            cache[m] = jax.lax.population_count(
-                xo & jnp.uint16(m)).astype(jnp.int32)
+            cache[m] = jax.lax.population_count(xo & m).astype(jnp.int32)
         return cache[m]
 
     for m in masks:
@@ -132,12 +159,11 @@ def _bic_variant_rows(d_of, raw_sum, spec, state_ref, state_row: int):
         b = (d * 2 < w).astype(jnp.int32) << si   # decision if prev inv 1
         a_pack = a if a_pack is None else a_pack | a
         b_pack = b if b_pack is None else b_pack | b
-    pre0, pre1 = jax.lax.associative_scan(
-        _compose_packed, (a_pack, b_pack), axis=0)
+    # identity step function: f(0) = 0, f(1) = 1 in every bit lane
+    pre0, pre1 = _prefix_scan(_compose_packed, (a_pack, b_pack), (0, -1))
     carried = state_ref[state_row:state_row + 1, :]          # [1, LB]
     inv = (carried & pre1) | (~carried & pre0)               # [TB, LB]
-    prev_inv = jnp.concatenate(
-        [jnp.broadcast_to(carried, inv[:1].shape), inv[:-1]], axis=0)
+    prev_inv = jnp.concatenate([carried, inv[:-1]], axis=0)
     flip_pack = inv ^ prev_inv
     state_ref[state_row:state_row + 1, :] = inv[-1:]
 
@@ -162,13 +188,15 @@ def _bic_variant_rows(d_of, raw_sum, spec, state_ref, state_row: int):
 
 
 def _parallel_block(x, spec, state_ref):
-    """Associative-scan (TPU) in-block algorithm: returns (rows, per-row
-    zero counts) and advances the carried scratch states."""
-    xc = state_ref[2:3, :].astype(jnp.uint16)
-    xp = jnp.concatenate([xc, x[:-1]], axis=0)
+    """Prefix-scan (TPU) in-block algorithm: returns (rows, per-row
+    zero counts) and advances the carried scratch states.
 
-    z = (x & jnp.uint16(NOT_SIGN)) == 0
-    zc = z.astype(jnp.int32)
+    Words are widened to int32 on load and zero masks stay int32: Mosaic
+    refuses 16-bit vector compares and bool-vector concatenates."""
+    x = x.astype(jnp.int32)
+    xp = jnp.concatenate([state_ref[2:3, :], x[:-1]], axis=0)
+
+    zc = ((x & NOT_SIGN) == 0).astype(jnp.int32)
 
     xo = x ^ xp                                      # shared XOR deltas
     d_of = _seg_distances(xo, (0xFFFF, MANT) + spec.unique_segments)
@@ -180,35 +208,33 @@ def _parallel_block(x, spec, state_ref):
     ]
 
     if spec.zvg:
-        held_c = state_ref[0:1, :].astype(jnp.uint16)        # [1, LB]
+        held_c = state_ref[0:1, :]                           # [1, LB]
         # held value = word at the latest non-zero cycle so far: a MAX
         # scan over (cycle << 16 | word), with zero cycles packed to -1
         it = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
-        packed = jnp.where(~z, (it << 16) | x.astype(jnp.int32), -1)
-        mx = jax.lax.associative_scan(jnp.maximum, packed, axis=0)
-        held = jnp.where(mx >= 0, (mx & 0xFFFF).astype(jnp.uint16), held_c)
+        packed = jnp.where(zc == 0, (it << 16) | x, -1)
+        (mx,) = _prefix_scan(lambda a, b: (jnp.maximum(a[0], b[0]),),
+                             (packed,), (-1,))
+        held = jnp.where(mx >= 0, mx & 0xFFFF, held_c)
         held_prev = jnp.concatenate([held_c, held[:-1]], axis=0)
         ho = held ^ held_prev
         h_of = _seg_distances(ho, (0xFFFF, MANT) + spec.unique_segments)
         hraw_sum = h_of(0xFFFF).sum(axis=0)
-        zp = state_ref[1:2, :] != 0
-        z_prev = jnp.concatenate(
-            [jnp.broadcast_to(zp, z[:1].shape), z[:-1]], axis=0)
+        z_prev = jnp.concatenate([state_ref[1:2, :], zc[:-1]], axis=0)
         rows.append(hraw_sum)                                       # zvg
         rows.append(h_of(MANT).sum(axis=0))
-        rows.append((z ^ z_prev).astype(jnp.int32).sum(axis=0))
+        rows.append((zc ^ z_prev).sum(axis=0))
 
     rows += _bic_variant_rows(d_of, raw_sum, spec, state_ref, 3)
     if spec.zvg:
         rows += _bic_variant_rows(h_of, hraw_sum, spec, state_ref, 4)
-        state_ref[0:1, :] = held[-1:].astype(jnp.int32)
+        state_ref[0:1, :] = held[-1:]
         state_ref[1:2, :] = zc[-1:]
-    state_ref[2:3, :] = x[-1:].astype(jnp.int32)
+    state_ref[2:3, :] = x[-1:]
 
     if spec.hist:
         for bit in range(WORD_BITS):
-            ones = (x >> jnp.uint16(bit)) & jnp.uint16(1)
-            rows.append(ones.astype(jnp.int32).sum(axis=0))
+            rows.append(((x >> bit) & 1).sum(axis=0))
 
     return rows, zc.sum(axis=1)
 
@@ -335,18 +361,18 @@ def _counters_kernel(x_ref, counts_ref, rowz_ref, state_ref, *,
 def fused_counters_pallas(x: jax.Array, spec: CounterSpec,
                           block_t: int | None = None,
                           block_l: int | None = None,
-                          interpret: bool = True,
+                          interpret: bool | None = None,
                           algo: str | None = None):
     """Run the fused counter pass over ``uint16[T, L]`` via Pallas.
 
     Returns ``(counts: int32[spec.n_rows, L], rowzeros: int32[T])``; the
     stream is encoded against an all-zeros initial bus state (every
     counter includes the ``init -> x[0]`` edge, matching the core
-    primitives). ``interpret=True`` executes on CPU; pass ``False`` on a
-    real TPU for the Mosaic lowering.
+    primitives). ``interpret=None`` compiles with Mosaic on a TPU and
+    runs the interpreter elsewhere.
 
     ``algo`` picks the in-block algorithm (see module docstring):
-    ``"parallel"`` (associative scans; default when compiled for TPU) or
+    ``"parallel"`` (prefix scans; default when compiled for TPU) or
     ``"scan"`` (one fused sequential loop; default in interpret mode,
     where the executing backend is a CPU). Bit-exact either way.
 
@@ -356,6 +382,7 @@ def fused_counters_pallas(x: jax.Array, spec: CounterSpec,
     to blow (results are bit-identical either way; only the grid
     changes).
     """
+    interpret = resolve_interpret(interpret)
     if algo is None:
         algo = "scan" if interpret else "parallel"
     if algo not in ("scan", "parallel"):
@@ -392,12 +419,14 @@ def fused_counters_pallas(x: jax.Array, spec: CounterSpec,
             # minor t axis, accumulated in place
             pl.BlockSpec((spec.n_rows, block_l), lambda l, t: (0, l)),
             # per-cycle zero counts: one private block per grid step
-            # (partial sums over lane blocks; the host reduces)
-            pl.BlockSpec((1, block_t), lambda l, t: (l, t)),
+            # (partial sums over lane blocks; the host reduces). The
+            # unit middle axis keeps the block's last two dims equal to
+            # the array's / lane-aligned, as Mosaic requires
+            pl.BlockSpec((None, 1, block_t), lambda l, t: (l, 0, t)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((spec.n_rows, Lp), jnp.int32),
-            jax.ShapeDtypeStruct((grid[0], Tp), jnp.int32),
+            jax.ShapeDtypeStruct((grid[0], 1, Tp), jnp.int32),
         ],
         scratch_shapes=[
             pltpu.VMEM((3 + spec.n_bic_states, block_l), jnp.int32)],
@@ -405,7 +434,7 @@ def fused_counters_pallas(x: jax.Array, spec: CounterSpec,
     )(x)
 
     counts = counts[:, :L]
-    rowzeros = rowz.sum(axis=0)[:T]
+    rowzeros = rowz.sum(axis=(0, 1))[:T]
     if pl_:
         # padded lanes are all-zero words: one zero per padded lane per
         # kept cycle (the padded lanes' own counter columns are sliced

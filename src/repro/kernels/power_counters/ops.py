@@ -25,6 +25,8 @@ from functools import partial
 
 import jax
 
+from repro.kernels.platform import resolve_interpret
+
 from .kernel import fused_counters_pallas
 from .ref import fused_counters_ref
 from .spec import CounterSpec
@@ -89,7 +91,6 @@ def edge_counters(bits: jax.Array, spec: CounterSpec,
     steer those.)
     """
     resolved = resolve_backend(backend)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    return _edge_counters(bits, spec, resolved, interpret, block_t,
+    return _edge_counters(bits, spec, resolved,
+                          resolve_interpret(interpret), block_t,
                           block_l)

@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.platform import resolve_interpret
+
 
 def _transitions_kernel(x_ref, xprev_ref, o_ref, *, mask: int):
     t = pl.program_id(1)
@@ -42,13 +44,14 @@ def _transitions_kernel(x_ref, xprev_ref, o_ref, *, mask: int):
 def transitions_pallas(x: jax.Array, mask: int = 0xFFFF,
                        init: jax.Array | None = None,
                        block_t: int = 256, block_l: int = 128,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool | None = None) -> jax.Array:
     """Per-lane transition counts via the Pallas kernel.
 
     Args/returns as :func:`repro.kernels.transitions.ref.transitions_ref`.
-    ``interpret=True`` executes on CPU (this container); pass ``False`` on a
-    real TPU for the Mosaic-compiled kernel.
+    ``interpret=None`` compiles with Mosaic on a TPU and runs the
+    interpreter elsewhere.
     """
+    interpret = resolve_interpret(interpret)
     x = x.astype(jnp.uint16)
     T, L = x.shape
     if init is None:

@@ -12,7 +12,7 @@ from .ref import transitions_ref
 @partial(jax.jit, static_argnames=("mask", "use_pallas", "interpret"))
 def count_transitions(x: jax.Array, mask: int = 0xFFFF,
                       use_pallas: bool = True,
-                      interpret: bool = True) -> jax.Array:
+                      interpret: bool | None = None) -> jax.Array:
     """Per-lane transition counts of a ``uint16[T, L]`` stream.
 
     ``use_pallas=False`` falls back to the pure-jnp oracle (useful inside

@@ -28,9 +28,10 @@ path they replace (differentially tested in
   attention math (the ``attend`` callable, closed over scale/softcap,
   runs on the gathered [B, pages*page_size] view inside the kernel).
 
-All three run ``interpret=True`` on CPU (bitwise vs XLA there -- the
-serve contract) and lower through Mosaic with ``interpret=False`` on
-TPU hardware.
+All three take ``interpret=None`` by default: the interpreter off TPU
+(bitwise vs XLA there -- the serve contract), Mosaic on a TPU. Mosaic
+refuses all three as they are shaped today, so the serve engine rejects
+``kernel_backend="pallas"`` on a TPU instead of running them.
 """
 from __future__ import annotations
 
@@ -41,6 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import resolve_interpret
 from repro.kernels.power_counters.kernel import _scan_block
 from repro.kernels.power_counters.spec import CounterSpec
 
@@ -94,7 +96,7 @@ def _row_matmul_kernel(x_ref, w_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gated_row_matmul(x: jax.Array, w: jax.Array,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool | None = None) -> jax.Array:
     """ZVG-gated ``x @ w`` for decode-shaped operands, bitwise vs XLA.
 
     Args:
@@ -121,7 +123,7 @@ def gated_row_matmul(x: jax.Array, w: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, N), lambda m: (m, 0)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w)
 
 
@@ -180,7 +182,7 @@ def fused_matmul_counters(a: jax.Array, w: jax.Array,
                           west_spec: CounterSpec,
                           north_spec: CounterSpec,
                           lanes_w: int, cols: int,
-                          interpret: bool = True):
+                          interpret: bool | None = None):
     """One fused pass: gated products + the whole coding-menu counter set.
 
     Args:
@@ -232,7 +234,7 @@ def fused_matmul_counters(a: jax.Array, w: jax.Array,
             pltpu.VMEM((3 + west_spec.n_bic_states, lanes_w), jnp.int32),
             pltpu.VMEM((3 + north_spec.n_bic_states, lanes_n), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, w)
     return product, wc, wz, nc, nz[0]
 
@@ -259,7 +261,7 @@ def _paged_attention_kernel(q_ref, kp_ref, vp_ref, pages_ref, len_ref,
 def fused_paged_attention(q: jax.Array, k_pool: jax.Array,
                           v_pool: jax.Array, pages: jax.Array,
                           lengths: jax.Array, attend,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: bool | None = None) -> jax.Array:
     """Paged decode attention with the page gather fused into the kernel.
 
     Args:
@@ -276,5 +278,5 @@ def fused_paged_attention(q: jax.Array, k_pool: jax.Array,
     return pl.pallas_call(
         functools.partial(_paged_attention_kernel, attend=attend),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k_pool, v_pool, pages, lengths)

@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.platform import resolve_interpret
+
 
 def _zvg_matmul_kernel(a_ref, b_ref, o_ref, g_ref, acc_ref):
     k = pl.program_id(2)
@@ -69,7 +71,7 @@ def _zvg_matmul_kernel(a_ref, b_ref, o_ref, g_ref, acc_ref):
 
 def zvg_matmul_pallas(a: jax.Array, b: jax.Array,
                       block_m: int = 128, block_n: int = 128,
-                      block_k: int = 128, interpret: bool = True):
+                      block_k: int = 128, interpret: bool | None = None):
     """Zero-gated ``a @ b`` with gating statistics.
 
     Args:
@@ -78,6 +80,7 @@ def zvg_matmul_pallas(a: jax.Array, b: jax.Array,
     Returns:
       ``(out: f32[M, N], gated: int32[M/BM, K/BK])``.
     """
+    interpret = resolve_interpret(interpret)
     M, K = a.shape
     K2, N = b.shape
     assert K == K2

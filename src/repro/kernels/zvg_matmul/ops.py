@@ -13,7 +13,7 @@ from .ref import zvg_matmul_ref
                                    "use_pallas", "interpret"))
 def zvg_matmul(a: jax.Array, b: jax.Array,
                block_m: int = 128, block_n: int = 128, block_k: int = 128,
-               use_pallas: bool = True, interpret: bool = True):
+               use_pallas: bool = True, interpret: bool | None = None):
     """Zero-gated matmul: ``(f32[M, N], gated int32[M/BM, K/BK])``.
 
     Numerically identical to ``a @ b``; the gating only skips work that is

@@ -15,21 +15,14 @@ from __future__ import annotations
 
 import jax
 import numpy as np
-
-try:  # jax >= 0.5: explicit axis types (Auto matches the old behaviour)
-    from jax.sharding import AxisType
-
-    def _axis_kwargs(n: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n}
-except ImportError:  # older jax: Auto is the only behaviour
-    def _axis_kwargs(n: int) -> dict:
-        return {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int | None = None, data: int | None = None):
@@ -57,4 +50,5 @@ def make_host_mesh(model: int | None = None, data: int | None = None):
             f"mesh {data}x{model} needs {data * model} devices; "
             f"host has {n}")
     arr = np.asarray(devs[:data * model]).reshape(data, model)
-    return jax.sharding.Mesh(arr, ("data", "model"), **_axis_kwargs(2))
+    return jax.sharding.Mesh(arr, ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)

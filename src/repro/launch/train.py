@@ -28,6 +28,7 @@ from repro.data.pipeline import DataConfig, make_source
 from repro.models import lm
 from repro.optim import AdamW, cosine_schedule
 from repro.runtime import fault, sharding as sh
+from repro.runtime.compile_cache import enable_compile_cache
 
 log = logging.getLogger("repro.train")
 
@@ -180,6 +181,7 @@ def main() -> None:
     args = ap.parse_args()
     tc = TrainConfig(**{f.name: getattr(args, f.name)
                         for f in dataclasses.fields(TrainConfig)})
+    enable_compile_cache()
     out = train(tc)
     log.info("done: final loss %.4f, median step %.0f ms, %d stragglers",
              out["final_loss"], out["median_step_time"] * 1e3,

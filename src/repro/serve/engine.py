@@ -127,6 +127,15 @@ class ServeEngine:
             raise ValueError(
                 f"unknown kernel_backend {scfg.kernel_backend!r}; "
                 f"expected one of {mm.BACKENDS}")
+        if scfg.kernel_backend == "pallas" and jax.default_backend() == "tpu":
+            raise NotImplementedError(
+                "kernel_backend='pallas' cannot run on a TPU yet: Mosaic "
+                "refuses the fused decode kernels gated_row_matmul, "
+                "fused_matmul_counters and fused_paged_attention "
+                "(repro.kernels.zvg_matmul.fused) as they are shaped "
+                "today; re-shaping them is ROADMAP item S4. Use "
+                "kernel_backend='ref': the power accountant still runs "
+                "the Mosaic-compiled counter kernel on a TPU.")
         self.cfg = cfg
         self.scfg = scfg
         self.mesh = mesh

@@ -31,12 +31,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 from jax import core as jcore
-
-try:  # jax >= 0.4.33: Literal lives in jax.extend.core (jax.core's copy
-    # is deprecated and later removed)
-    from jax.extend.core import Literal as _Literal
-except ImportError:  # pragma: no cover - very old jax
-    _Literal = jcore.Literal
+from jax.extend.core import Literal as _Literal
 
 # Primitives that carry a sub-jaxpr the interpreter must recurse into so
 # inner matmuls are seen with concrete operands (a plain bind would execute
@@ -209,7 +204,6 @@ class _Interpreter:
         # XLA-like liveness: free each value after its last textual use,
         # otherwise the interpreter pins every intermediate of the whole
         # forward simultaneously and peak memory dwarfs the jitted run
-        drop = getattr(jcore, "DropVar", ())
         live_out = {v for v in jaxpr.outvars
                     if not isinstance(v, _Literal)}
         last_use: dict = {}
@@ -222,7 +216,7 @@ class _Interpreter:
             invals = [read(v) for v in eqn.invars]
             outvals = self.eval_eqn(eqn, invals)
             for v, val in zip(eqn.outvars, outvals):
-                if not isinstance(v, drop):
+                if not isinstance(v, jcore.DropVar):
                     write(v, val)
             for v in eqn.invars:
                 if (not isinstance(v, _Literal) and last_use.get(v) == i

@@ -364,21 +364,22 @@ def test_selection_on_traced_cnn_beats_fixed_design(resnet_selection):
 
 
 #: PR 3's headline selection outcome on resnet50@64px: per-site greedy
-#: selection saves 9.775% vs the fixed proposed design's 9.647%, with
+#: selection saves 9.774% vs the fixed proposed design's 9.646%, with
 #: every one of the 54 sites preferring an input-side-BIC variant over
-#: the paper default. Floats regenerated per docs/testing.md after a
-#: container image update drifted the traced activations a few ulp past
-#: the seed recording's 1e-6 window (site counts and design picks were
-#: unchanged); verified identical under ``--backend ref`` and
-#: ``--backend pallas`` before recording.
+#: the paper default. Floats regenerated per docs/testing.md under JAX
+#: 0.9.0: XLA:CPU there computes the traced activations a few ulp apart
+#: from the earlier recording, which moved both savings past the 1e-6
+#: window and flipped one near-tie site from bic-west to mant-exp (site
+#: count, changed count and designs used are unchanged). Identical under
+#: ``--backend ref`` and ``--backend pallas`` before recording.
 GOLDEN_SELECTION = {
     "n_sites": 54,
     "n_changed": 54,
     "designs_used": ["bic-west", "mant-exp"],
-    "saving_selected": 0.09774634926699788,
-    "saving_fixed": 0.09647415704665074,
-    "n_bic_west": 37,
-    "n_mant_exp": 17,
+    "saving_selected": 0.09773778119252752,
+    "saving_fixed": 0.09646395438273403,
+    "n_bic_west": 36,
+    "n_mant_exp": 18,
 }
 
 

@@ -141,6 +141,35 @@ def test_unknown_backend_rejected(model):
             pass
 
 
+@pytest.mark.parametrize("paged", [False, True])
+def test_pallas_backend_rejected_on_tpu(model, monkeypatch, paged):
+    """On a TPU the fused decode kernels would meet Mosaic, which refuses
+    them as shaped today: the engine must raise, neither interpreting
+    them nor swapping in "ref"."""
+    cfg, params = model
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    kw = {"paging": PagingConfig(page_size=PS)} if paged else {}
+    with pytest.raises(NotImplementedError, match="S4"):
+        ServeEngine(params, cfg, ServeConfig(kernel_backend="pallas", **kw))
+
+
+def test_fused_kernels_interpret_only_off_tpu(monkeypatch):
+    """``interpret=None`` (the fused kernels' default) means the Pallas
+    interpreter on CPU and Mosaic on a TPU; explicit values pass."""
+    from repro.kernels.platform import resolve_interpret
+    from repro.kernels.zvg_matmul.fused import gated_row_matmul
+
+    assert resolve_interpret(None) is True
+    x = jax.random.normal(jax.random.key(1), (3, 8))
+    w = jax.random.normal(jax.random.key(2), (8, 5))
+    np.testing.assert_array_equal(gated_row_matmul(x, w),
+                                  gated_row_matmul(x, w, interpret=True))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert resolve_interpret(None) is False
+    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False
+
+
 def test_backend_scope_is_decode_only(model):
     """Building and running a pallas engine never leaks the dispatch
     global: code outside the decode jit always sees "ref"."""
