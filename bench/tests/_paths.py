@@ -1,0 +1,10 @@
+"""Put the checkout root and ``src`` on ``sys.path`` for the benchmark's
+tests, however pytest is started."""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+for p in (ROOT, os.path.join(ROOT, "src")):
+    if p not in sys.path:
+        sys.path.insert(0, p)
