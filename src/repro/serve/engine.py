@@ -60,6 +60,7 @@ from .cache import SlotCache
 from .power import PowerAccountant
 from .request import Request, RequestStatus
 from .scheduler import FIFOScheduler
+from .spans import fetch, span
 
 #: mixers whose decode reads the cache strictly by position mask, making
 #: right-padded prefill exact (see lm.make_slot_prefill_step); recurrent
@@ -202,9 +203,14 @@ class ServeEngine:
         mixers = {parse_spec(s)[0]
                   for s in (*cfg.pattern, *cfg.head, *cfg.tail)}
         self._pad_safe = mixers <= _PAD_SAFE_MIXERS
+        # host_syncs: blocking device-to-host fetches (spans.fetch), the
+        # accountant's included -- it counts into this same dict
+        self.stats = {"steps": 0, "decode_steps": 0, "tokens": 0,
+                      "occupancy_sum": 0, "peak_live": 0, "host_syncs": 0}
         self.accountant = (PowerAccountant(
                                scfg.monitor, scfg.power_sample_every,
-                               kernel_backend=scfg.kernel_backend)
+                               kernel_backend=scfg.kernel_backend,
+                               stats=self.stats)
                            if scfg.power_monitor else None)
         self.telemetry = None
         if scfg.telemetry is not None:
@@ -229,8 +235,6 @@ class ServeEngine:
             weights = [(site, jnp.asarray(jax.device_get(w)))
                        for site, w in weights]
         self._power_weights = weights
-        self.stats = {"steps": 0, "decode_steps": 0, "tokens": 0,
-                      "occupancy_sum": 0, "peak_live": 0}
 
     def _build_state(self):
         """Cache + scheduler + decode batch width (subclass hook)."""
@@ -260,36 +264,50 @@ class ServeEngine:
     def step(self) -> list[Request]:
         """One engine iteration: admit, one shared decode, retire.
         Returns the requests retired during this step."""
-        self._apply_design_swaps()
-        retired: list[Request] = []
-        self._admission_phase(retired)
-        live = self._decode_ready(retired)
-        if live:
-            inputs = self.cache.decode_inputs()
-            if self.accountant is not None and self.accountant.tick(live):
-                x = self._embed(self.params, inputs)
-                for site, w in self._power_weights:
-                    self.accountant.record_decode(live, x[:, 0], w, site)
-                self.accountant.mark_sampled(live)
+        with span("step"):
+            self._apply_design_swaps()
+            retired: list[Request] = []
+            self._admission_phase(retired)
+            live = self._decode_ready(retired)
+            if live:
+                self._decode_phase(live, retired)
+            self.stats["steps"] += 1
+        return retired
+
+    def _decode_phase(self, live: list[int], retired: list[Request]) -> None:
+        inputs = self.cache.decode_inputs()
+        if self.accountant is not None:
+            with span("accountant"):
+                self._record_decode_power(live, inputs)
+        with span("decode"):
             logits, self.cache.states = self._decode(
                 self.params, self.cache.states, inputs)
+        with span("sample"):
             self._key, sub = jax.random.split(self._key)
-            toks = np.asarray(jax.device_get(sampling.sample_tokens(
+            toks = np.asarray(fetch(sampling.sample_tokens(
                 sub, logits, jnp.asarray(self._temp),
-                jnp.asarray(self._topk))))
-            for slot in live:
-                req = self._running[slot]
-                tok = int(toks[slot])
-                self.cache.advance(slot, tok)
-                req.generated.append(tok)
-                self.stats["tokens"] += 1
-                self._maybe_retire(req, retired)
-            self.stats["decode_steps"] += 1
-            self.stats["occupancy_sum"] += len(live)
-            self.stats["peak_live"] = max(self.stats["peak_live"],
-                                          len(live))
-        self.stats["steps"] += 1
-        return retired
+                jnp.asarray(self._topk)), self.stats))
+        for slot in live:
+            req = self._running[slot]
+            tok = int(toks[slot])
+            self.cache.advance(slot, tok)
+            req.generated.append(tok)
+            self.stats["tokens"] += 1
+            self._maybe_retire(req, retired)
+        self.stats["decode_steps"] += 1
+        self.stats["occupancy_sum"] += len(live)
+        self.stats["peak_live"] = max(self.stats["peak_live"], len(live))
+
+    def _record_decode_power(self, live: list[int], inputs: dict) -> None:
+        """Stream this step's embedded decode inputs through the
+        monitored sites, when the accountant's cadence samples it."""
+        if not self.accountant.tick(live):
+            return
+        with span("accountant.embed"):
+            x = self._embed(self.params, inputs)[:, 0]
+        for site, w in self._power_weights:
+            self.accountant.record_decode(live, x, w, site)
+        self.accountant.mark_sampled(live)
 
     def _apply_design_swaps(self) -> None:
         """Commit any design flips the online selector staged since the
@@ -304,7 +322,8 @@ class ServeEngine:
     def _admission_phase(self, retired: list[Request]) -> None:
         while self.cache.n_free and self.scheduler.n_pending:
             req = self.scheduler.pop_admissible(1)[0]
-            self._admit(req)
+            with span("admit", uid=req.uid):
+                self._admit(req)
             self._maybe_retire(req, retired)   # max_new == 1 / prompt EOS
 
     def _decode_ready(self, retired: list[Request]) -> list[int]:
@@ -357,8 +376,9 @@ class ServeEngine:
         self.stats["tokens"] += 1
         self._running[slot] = req
         if self.accountant is not None:
-            self.accountant.begin(slot, req.uid, length)
-            self._record_prefill_power(slot, toks, 0, length)
+            with span("accountant"):
+                self.accountant.begin(slot, req.uid, length)
+                self._record_prefill_power(slot, toks, 0, length)
 
     def _sample_first(self, req: Request, logits) -> int:
         """Install the request's sampling params on its slot and draw its
@@ -367,10 +387,10 @@ class ServeEngine:
         self._temp[slot] = req.sampling.temperature
         self._topk[slot] = req.sampling.top_k
         self._key, sub = jax.random.split(self._key)
-        return int(jax.device_get(sampling.sample_tokens(
+        return int(fetch(sampling.sample_tokens(
             sub, logits, jnp.full((1,), req.sampling.temperature,
                                   jnp.float32),
-            jnp.full((1,), req.sampling.top_k, jnp.int32)))[0])
+            jnp.full((1,), req.sampling.top_k, jnp.int32)), self.stats)[0])
 
     def _record_prefill_power(self, slot: int, toks: np.ndarray,
                               lo: int, length: int) -> None:
@@ -383,8 +403,9 @@ class ServeEngine:
         padding never leaks into ``[lo, length)``. ``lo > 0`` is the
         prefix-reuse case: the request pays only for the suffix it
         actually computed (the first-payer contract)."""
-        x = self._embed(self.params,
-                        {"tokens": jnp.asarray(toks)})[:, lo:length]
+        with span("accountant.embed"):
+            x = self._embed(self.params,
+                            {"tokens": jnp.asarray(toks)})[:, lo:length]
         for site, w in self._power_weights:
             self.accountant.record_prefill(slot, x, w, site)
 
@@ -397,17 +418,20 @@ class ServeEngine:
 
     def _retire(self, req: Request, reason: str,
                 retired: list[Request]) -> None:
-        slot = req.slot
-        if self.accountant is not None:
-            req.power = self.accountant.finish(slot, len(req.generated))
-        self._release_slot(slot)
-        self._temp[slot] = 0.0
-        self._topk[slot] = 0
-        self._running.pop(slot)
-        req.status = RequestStatus.FINISHED
-        req.finish_reason = reason
-        req.finish_step = self.stats["steps"]
-        retired.append(req)
+        with span("retire", uid=req.uid):
+            slot = req.slot
+            if self.accountant is not None:
+                with span("accountant"):
+                    req.power = self.accountant.finish(
+                        slot, len(req.generated))
+            self._release_slot(slot)
+            self._temp[slot] = 0.0
+            self._topk[slot] = 0
+            self._running.pop(slot)
+            req.status = RequestStatus.FINISHED
+            req.finish_reason = reason
+            req.finish_step = self.stats["steps"]
+            retired.append(req)
 
     def _release_slot(self, slot: int) -> None:
         self.cache.release(slot)

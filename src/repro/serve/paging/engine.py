@@ -58,6 +58,7 @@ from repro.models.transformer import parse_spec
 
 from ..engine import ServeEngine, _PAD_SAFE_MIXERS
 from ..request import Request, RequestStatus
+from ..spans import span
 from .cache import PagedKVCache
 from .prefix import PrefixCache
 from .scheduler import ClassScheduler
@@ -132,10 +133,13 @@ class PagedServeEngine(ServeEngine):
     # ----------------------------------------------------------- admission
     def _admission_phase(self, retired: list[Request]) -> None:
         for row in sorted(self._jobs):
-            self._pump_chunk(row, retired)
+            with span("admit", uid=self._jobs[row].req.uid):
+                self._pump_chunk(row, retired)
         while self.cache.n_free and self.scheduler.n_pending:
             req = self.scheduler.pop_admissible(1)[0]
-            if not self._try_admit(req, retired):
+            with span("admit", uid=req.uid):
+                admitted = self._try_admit(req, retired)
+            if not admitted:
                 # head-of-class blocked on pages: stop admitting (its
                 # seniority is preserved; capacity frees as rows retire)
                 self.scheduler.requeue_front(req)
@@ -171,20 +175,20 @@ class PagedServeEngine(ServeEngine):
         self._running[row] = req
         if shared:
             self.stats["prefix_hit_requests"] += 1
-        if self.accountant is not None:
-            acc = self._suspended.pop(req.uid, None)
-            if acc is not None:
-                self.accountant.resume(row, acc)
-            else:
-                self.accountant.begin(row, req.uid, req.prompt_len)
         bucket = max(self._bucket(length), length)
         toks = np.zeros((1, bucket), np.int32)
         toks[0, :length] = seq
         if self.accountant is not None:
-            # the WHOLE computed extent in one call, even when prefill
-            # itself streams in chunks: BIC/ZVG counters are row-stream
-            # statistics, additive only over one contiguous recording
-            self._record_prefill_power(row, toks, start, length)
+            with span("accountant"):
+                acc = self._suspended.pop(req.uid, None)
+                if acc is not None:
+                    self.accountant.resume(row, acc)
+                else:
+                    self.accountant.begin(row, req.uid, req.prompt_len)
+                # the WHOLE computed extent in one call, even when prefill
+                # itself streams in chunks: BIC/ZVG counters are row-stream
+                # statistics, additive only over one contiguous recording
+                self._record_prefill_power(row, toks, start, length)
         if start == 0 and (pcfg.prefill_chunk == 0
                            or length <= pcfg.prefill_chunk):
             # dense path: the exact admission the slot engine runs, then

@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from repro.core import monitor
 from repro.trace.capture import CaptureConfig, TraceCapture
 
+from .spans import fetch, span
+
 #: name of the pseudo-design that prices each counter recording under
 #: the design that was ACTIVE when it was recorded (closed-loop online
 #: actuation, repro.serve.telemetry); rides report machinery like
@@ -108,8 +110,9 @@ def actuated_stream_energy(records, primary: str) -> float:
     return total
 
 
-def gather_local(a):
-    """Bring a (possibly mesh-sharded) operand onto the default device.
+def gather_local(a, stats: dict):
+    """Bring a (possibly mesh-sharded) operand onto the default device
+    (the fetch counts in ``stats["host_syncs"]``).
 
     The accountant's contract is that its numbers are sums of
     ``monitor.stream_counters`` outputs -- the SAME outputs whether the
@@ -120,7 +123,7 @@ def gather_local(a):
     for anything already on one device.
     """
     if isinstance(a, jax.Array) and len(a.sharding.device_set) > 1:
-        return jnp.asarray(jax.device_get(a))
+        return jnp.asarray(fetch(a, stats))
     return a
 
 
@@ -435,7 +438,8 @@ class PowerAccountant:
     """Per-slot incremental accounting, one live request per slot."""
 
     def __init__(self, mcfg: monitor.MonitorConfig = monitor.DEFAULT_MONITOR,
-                 sample_every: int = 1, kernel_backend: str = "ref"):
+                 sample_every: int = 1, kernel_backend: str = "ref",
+                 stats: dict | None = None):
         if sample_every < 1:
             raise ValueError(f"sample_every must be >= 1: {sample_every}")
         if kernel_backend not in ("ref", "pallas"):
@@ -444,6 +448,9 @@ class PowerAccountant:
                 f"expected 'ref' or 'pallas'")
         self.mcfg = mcfg
         self.sample_every = sample_every
+        # counts the accountant's device-to-host fetches under
+        # "host_syncs"; the engine passes its own stats dict
+        self.stats = {"host_syncs": 0} if stats is None else stats
         # decode accounting uses the counter-producer/assembler split for
         # single-geometry menus (the fused matmul+counter Pallas pass
         # when kernel_backend="pallas", the edge_counters reference
@@ -653,35 +660,39 @@ class PowerAccountant:
                        site: str) -> None:
         """One prefill matmul for the slot's request: ``acts [..., K]`` (the
         request's real prompt rows only -- no padding), ``weight [K, N]``."""
-        acts, weight = gather_local(acts), gather_local(weight)
-        A = acts.reshape(-1, acts.shape[-1])
-        m = A.shape[0]
-        # pre-sample rows to a power-of-two budget so the jitted stream
-        # model compiles O(log max_rows) shapes total, not one per
-        # distinct prompt length (the accounting analogue of the engine's
-        # prefill buckets); even-stride sampling + back-scaling keeps
-        # ratios exact and totals unbiased
-        ms = 1 << (min(m, self.mcfg.max_rows).bit_length() - 1)
-        a2, w2 = monitor.subsample_operands(
-            monitor._subsample(A, ms, 0), weight, self.mcfg)
-        counters = {k: float(v) for k, v in jax.device_get(
-            monitor.stream_counters(a2, w2, self.mcfg)).items()}
-        zf = counters.pop("zero_fraction")
-        factor = monitor.sampled_fraction_scale(
-            m, A.shape[1], weight.shape[1], self.mcfg, sampled_m=ms)
-        scaled = {k: v * factor for k, v in counters.items()}
-        acc = self._slots[slot]
-        name = f"prefill/{site}"
-        rec = acc.prefill.get(name)
-        if rec is None:
-            rec = acc.prefill[name] = _SiteRec(
-                (1, A.shape[0], A.shape[1], weight.shape[1]))
-        else:
-            # a re-prefill after preemption streams more rows through the
-            # same site: grow the booked MAC extent with the energy
-            rec.shape = (1, rec.shape[1] + A.shape[0],
-                         rec.shape[2], rec.shape[3])
-        rec.add(scaled, zf, self.design_for(name))
+        with span("accountant.counters"):
+            acts = gather_local(acts, self.stats)
+            weight = gather_local(weight, self.stats)
+            A = acts.reshape(-1, acts.shape[-1])
+            m = A.shape[0]
+            # pre-sample rows to a power-of-two budget so the jitted
+            # stream model compiles O(log max_rows) shapes total, not one
+            # per distinct prompt length (the accounting analogue of the
+            # engine's prefill buckets); even-stride sampling +
+            # back-scaling keeps ratios exact and totals unbiased
+            ms = 1 << (min(m, self.mcfg.max_rows).bit_length() - 1)
+            a2, w2 = monitor.subsample_operands(
+                monitor._subsample(A, ms, 0), weight, self.mcfg)
+            out = monitor.stream_counters(a2, w2, self.mcfg)
+        fetched = fetch(out, self.stats)
+        with span("accountant.book"):
+            counters = {k: float(v) for k, v in fetched.items()}
+            zf = counters.pop("zero_fraction")
+            factor = monitor.sampled_fraction_scale(
+                m, A.shape[1], weight.shape[1], self.mcfg, sampled_m=ms)
+            scaled = {k: v * factor for k, v in counters.items()}
+            acc = self._slots[slot]
+            name = f"prefill/{site}"
+            rec = acc.prefill.get(name)
+            if rec is None:
+                rec = acc.prefill[name] = _SiteRec(
+                    (1, A.shape[0], A.shape[1], weight.shape[1]))
+            else:
+                # a re-prefill after preemption streams more rows through
+                # the same site: grow the booked MAC extent with the energy
+                rec.shape = (1, rec.shape[1] + A.shape[0],
+                             rec.shape[2], rec.shape[3])
+            rec.add(scaled, zf, self.design_for(name))
 
     def tick(self, slots: list[int]) -> bool:
         """Advance live slots by one decode step; True when this step
@@ -709,30 +720,34 @@ class PowerAccountant:
         """One decode-step matmul across the whole batch: ``acts [B, K]``
         (row per KV slot), ``weight [K, N]``. Only rows in ``slots`` are
         credited; the step must have been announced with :meth:`tick`."""
-        A, W = gather_local(acts), gather_local(weight)
-        if self._split:
-            if self._fused:
-                wc, wz, nc, nz, _ = _fused_decode_counters(A, W, self.mcfg)
+        with span("accountant.counters"):
+            A = gather_local(acts, self.stats)
+            W = gather_local(weight, self.stats)
+            if self._split:
+                if self._fused:
+                    wc, wz, nc, nz, _ = _fused_decode_counters(A, W,
+                                                               self.mcfg)
+                else:
+                    wc, wz, nc, nz = _ref_decode_counters(A, W, self.mcfg)
+                out = _assemble_decode(wc, wz, nc, nz, self.mcfg,
+                                       min(W.shape[1], self.mcfg.max_cols))
             else:
-                wc, wz, nc, nz = _ref_decode_counters(A, W, self.mcfg)
-            per_row = jax.device_get(_assemble_decode(
-                wc, wz, nc, nz, self.mcfg,
-                min(W.shape[1], self.mcfg.max_cols)))
-        else:
-            per_row = jax.device_get(_rows_counters(A, W, self.mcfg))
-        for s in slots:
-            acc = self._slots[s]
-            if not acc.due:
-                continue
-            row = {k: float(v[s]) for k, v in per_row.items()}
-            zf = row.pop("zero_fraction")
-            factor = monitor.sampled_fraction_scale(
-                1, acts.shape[1], weight.shape[1], self.mcfg)
-            scaled = {k: v * factor for k, v in row.items()}
-            name = f"decode/{site}"
-            rec = acc.decode.setdefault(
-                name, _SiteRec((1, 1, acts.shape[1], weight.shape[1])))
-            rec.add(scaled, zf, self.design_for(name))
+                out = _rows_counters(A, W, self.mcfg)
+        per_row = fetch(out, self.stats)
+        with span("accountant.book"):
+            for s in slots:
+                acc = self._slots[s]
+                if not acc.due:
+                    continue
+                row = {k: float(v[s]) for k, v in per_row.items()}
+                zf = row.pop("zero_fraction")
+                factor = monitor.sampled_fraction_scale(
+                    1, acts.shape[1], weight.shape[1], self.mcfg)
+                scaled = {k: v * factor for k, v in row.items()}
+                name = f"decode/{site}"
+                rec = acc.decode.setdefault(
+                    name, _SiteRec((1, 1, acts.shape[1], weight.shape[1])))
+                rec.add(scaled, zf, self.design_for(name))
 
     def mark_sampled(self, slots: list[int]) -> None:
         """Book that this step's records covered these slots (called once

@@ -68,6 +68,17 @@ def reference(model):
     return _run(model, None)
 
 
+def _assert_same_stats(eng, ref_eng):
+    """Every engine count equal, but host syncs: a mesh engine's
+    accountant also gathers its replicated operand once per monitored
+    site and recording (every decode step, every admission)."""
+    got, want = dict(eng.stats), dict(ref_eng.stats)
+    extra = got.pop("host_syncs") - want.pop("host_syncs")
+    assert got == want
+    assert extra == len(eng._power_weights) * (
+        eng.stats["decode_steps"] + eng.cache.allocations)
+
+
 def _mesh(name):
     data, mdl = name.split("x")
     return make_host_mesh(data=int(data), model=int(mdl))
@@ -107,7 +118,7 @@ def test_slot_churn_equivalence(model, reference, mesh_name):
     assert {r.uid: (r.start_step, r.finish_step) for r in fin} == \
            {r.uid: (r.start_step, r.finish_step) for r in ref_fin}
     assert eng.cache.allocations == ref_eng.cache.allocations
-    assert eng.stats == ref_eng.stats
+    _assert_same_stats(eng, ref_eng)
 
 
 def test_greedy_rows_exact_beside_stochastic_cobatch(model):
@@ -153,7 +164,7 @@ def test_paged_engine_on_mesh_bit_exact(model, mesh_name):
         assert got.power.energy == want.power.energy, got.uid
     assert eng.trace_report().aggregate() == \
            ref_eng.trace_report().aggregate()
-    assert eng.stats == ref_eng.stats
+    _assert_same_stats(eng, ref_eng)
 
 
 def test_paged_chunked_prefix_on_mesh_token_equal(model):
