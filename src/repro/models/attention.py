@@ -208,33 +208,52 @@ def paged_chunk_attention(q, k_cache, v_cache, positions_q, *, scale=None,
 
 
 def decode_attention(q, k_cache, v_cache, cache_len, *, scale=None,
-                     softcap=0.0, window: int = 0, constrain_q: bool = True):
-    """Single-token decode: q ``[B, 1, H, D]`` against ``[B, Smax, Hkv, D]``
-    caches holding ``cache_len`` valid entries."""
+                     softcap=0.0, constrain_q: bool = True):
+    """Single-token decode: q ``[B, 1, H, D]`` against lane-dense
+    ``[B, Smax, Hkv * D]`` caches (a ``[B, Smax, Hkv, D]`` view is
+    flattened to that) holding ``cache_len`` valid entries.
+
+    Both products contract or keep the cache's flat feature dim whole, so
+    the cache is read in the layout it is stored in (a ``[.., Hkv, D]``
+    view with D = 64 fills half a 128-lane tile and makes the compiler
+    relayout the whole cache every step). The scores multiply the cache
+    by a block-diagonal query ``[B, H, Hkv * D]`` (head h holds its
+    values in its KV group's block, zeros elsewhere); the output
+    multiplies the probabilities by the whole value row and keeps each
+    head's own block. That is Hkv times the useful MXU work, all of it
+    on data already read, and exact: the off-block products are zeros.
+    Both products accumulate in float32.
+    """
     b, _, h, d = q.shape
-    hkv = k_cache.shape[2]
-    qg = _group_q(q, hkv)
-    # Match q's layout to the cache's (head_dim sharded on the model axis
-    # when kv-heads don't divide it): the scores contraction then runs on
-    # partial shards + a small all-reduce instead of GSPMD all-gathering
-    # the far larger KV cache every step. (§Perf cell A.) Gated off for
-    # M-RoPE queries, whose frequency-gather interacts badly with a forced
-    # hd-sharding (measured: 800 GiB of per-layer cache all-to-alls).
+    k_cache = k_cache.reshape(k_cache.shape[:2] + (-1,))
+    v_cache = v_cache.reshape(v_cache.shape[:2] + (-1,))
+    hkv = k_cache.shape[-1] // d
+    rep = h // hkv
+    own = jnp.eye(hkv, dtype=bool)[None, :, None, :, None]  # [1,k,1,j,1]
+    qbd = jnp.where(own, _group_q(q, hkv)[:, 0, :, :, None, :],
+                    jnp.zeros((), q.dtype)).reshape(b, h, hkv * d)
+    # Match q's layout to the cache's (flat feature dim sharded on the
+    # model axis): the scores contraction then runs on partial shards + a
+    # small all-reduce instead of GSPMD all-gathering the far larger KV
+    # cache every step. (§Perf cell A.) Gated off for M-RoPE queries,
+    # whose frequency-gather interacts badly with a forced feature
+    # sharding (measured: 800 GiB of per-layer cache all-to-alls).
     if constrain_q:
-        qg = _try_constrain(qg, (None, None, None, None, "model"))
+        qbd = _try_constrain(qbd, (None, None, "model"))
     scale = scale if scale is not None else d ** -0.5
-    scores = jnp.einsum("bqkrd,bskd->bkrqs", qg, k_cache
-                        ).astype(jnp.float32) * scale
+    scores = jnp.einsum("bhf,btf->bht", qbd, k_cache,
+                        preferred_element_type=jnp.float32) * scale
     if softcap > 0:
         scores = jnp.tanh(scores / softcap) * softcap
     kpos = jnp.arange(k_cache.shape[1])
     mask = kpos[None, :] < cache_len[:, None]           # [B, Smax]
-    if window > 0:
-        mask &= kpos[None, :] >= cache_len[:, None] - window
-    scores = jnp.where(mask[:, None, None, None], scores, NEG_INF)
+    scores = jnp.where(mask[:, None, :], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkrqs,bskd->bqkrd", probs, v_cache)
-    return out.reshape(b, 1, h, v_cache.shape[-1])
+    full = jnp.einsum("bht,btf->bhf", probs, v_cache,
+                      preferred_element_type=jnp.float32)
+    dv = v_cache.shape[-1] // hkv
+    out = jnp.diagonal(full.reshape(b, hkv, rep, hkv, dv), axis1=1, axis2=3)
+    return out.transpose(0, 3, 1, 2).reshape(b, 1, h, dv).astype(q.dtype)
 
 
 # ----------------------------------------------------------------- MLA ----

@@ -305,14 +305,21 @@ def make_decode_state(cfg: ArchConfig, batch: int, cache_len: int,
                       dtype=jnp.bfloat16) -> dict:
     """Zero-initialized decode states matching ``apply_stack``'s structure.
 
-    The dry-run turns this into ShapeDtypeStructs via ``jax.eval_shape``.
+    Full-attention (``attn``) K and V are lane-dense ``[B, T, kv * hd]``
+    (scanned groups ``[G, B, T, kv * hd]``): with hd = 64 a ``[.., kv,
+    hd]`` leaf fills half a 128-lane tile, so the compiler stores it
+    T-minor and relays out each layer's slice to write a row; the flat
+    layout is the one both the row write and the attention read use
+    (``transformer.apply_attention``). Other mixers' states keep their
+    own shapes. The dry-run turns this into ShapeDtypeStructs via
+    ``jax.eval_shape``.
     """
     def block_state(spec: str):
         mixer, _ = T.parse_spec(spec)
         kv, hd = cfg.n_kv_heads, cfg.hd
         if mixer == "attn":
-            return (jnp.zeros((batch, cache_len, kv, hd), dtype),
-                    jnp.zeros((batch, cache_len, kv, hd), dtype))
+            return (jnp.zeros((batch, cache_len, kv * hd), dtype),
+                    jnp.zeros((batch, cache_len, kv * hd), dtype))
         if mixer == "local":
             w = cfg.window
             return (jnp.zeros((batch, w, kv, hd), dtype),

@@ -159,21 +159,38 @@ def _page_targets(pages, positions, page_size):
     return jnp.where(ok, pg, 0), jnp.clip(pos, 0) % page_size
 
 
-def _gather_pages(pool, pages):
+def _gather_pages(pool, pages, layer=None):
     """Dense per-row logical view of a page pool: ``pool [P, ps, ...]`` +
-    ``pages [B, MP]`` -> ``[B, MP * ps, ...]``. Unallocated table entries
-    point at the trash page; whatever junk they contribute sits at logical
-    positions beyond the row's write coverage, which every caller masks by
-    position -- the same argument that makes a dead slot row harmless in
-    the dense cache."""
+    ``pages [B, MP]`` -> ``[B, MP * ps, ...]`` (``pool [G, P, ps, ...]``
+    and this block's ``layer`` for a stacked pool). Unallocated table
+    entries point at the trash page; whatever junk they contribute sits at
+    logical positions beyond the row's write coverage, which every caller
+    masks by position -- the same argument that makes a dead slot row
+    harmless in the dense cache."""
     b, mp = pages.shape
-    view = jnp.take(pool, pages, axis=0)          # [B, MP, ps, ...]
-    return view.reshape((b, mp * pool.shape[1]) + pool.shape[2:])
+    view = pool[pages] if layer is None else pool[layer, pages]
+    return view.reshape((b, mp * view.shape[2]) + view.shape[3:])
+
+
+def _layer_of(cache, layer):
+    """One layer's cache: ``cache`` itself, or its slice ``layer`` of a
+    stacked ``[G, ...]`` leaf (read, never written back)."""
+    if layer is None:
+        return cache
+    return jax.lax.dynamic_index_in_dim(cache, layer, 0, keepdims=False)
 
 
 # --------------------------------------------------------------- attention
 def apply_attention(p, x, cfg: ArchConfig, *, local: bool, positions,
-                    state=None, prefill=False, cache_len=0, pages=None):
+                    state=None, prefill=False, cache_len=0, pages=None,
+                    layer=None):
+    """Attention block. A full-attention cache (``local=False``) is
+    lane-dense: ``(k, v)`` leaves ``[B, T, kv * hd]``, paged pools
+    ``[P, ps, kv * hd]``. With ``layer`` (a traced index) the leaves are
+    the whole stacked ``[G, ...]`` caches of the layer scan: the step's
+    rows are scattered straight into them at ``[layer, row, position]``
+    and the attention reads the layer's slice inside its products, so no
+    layer slice is materialised, relaid out or written back."""
     b, s, d = x.shape
     h, kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     scale = cfg.attn_scale or None
@@ -190,69 +207,73 @@ def apply_attention(p, x, cfg: ArchConfig, *, local: bool, positions,
     v = proj("wv", "bv", kv)
     q, k = _rope_qk(cfg, q, k, positions)
 
-    if state is not None and pages is not None:   # ---- paged decode/chunk
-        # Pool-backed cache: scatter this step's keys/values into the
-        # row's pages, then GATHER a dense logical view and reuse the
-        # dense decode attention unchanged -- masked positions contribute
-        # exact zeros, so a single-token step is bit-identical to the
-        # slot cache's dense path (tests/test_serve_paging.py pins it).
-        kp, vp = state                            # [P, ps, kv, hd] pools
-        pgs = kp.shape[1]
-        pg, off = _page_targets(pages, positions, pgs)
-        kp = kp.at[pg, off].set(k.astype(kp.dtype))
-        vp = vp.at[pg, off].set(v.astype(vp.dtype))
-        if s == 1:
+    if state is not None and not local:         # ---- full-attention cache
+        kc, vc = state
+        if pages is not None:
+            pg, off = _page_targets(pages, positions, kc.shape[-2])
+            at = (pg, off)
+        else:
             bpos = _decode_batch_pos(cfg, positions)
+            at = (jnp.arange(b)[:, None], bpos[:, None])
+        if layer is not None:
+            at = (layer,) + at
+        kc = kc.at[at].set(k.reshape(b, s, kv * hd).astype(kc.dtype))
+        vc = vc.at[at].set(v.reshape(b, s, kv * hd).astype(vc.dtype))
 
-            def attend(qq, kc, vc, lengths):
-                return A.decode_attention(qq, kc, vc, lengths, scale=scale,
-                                          softcap=cfg.attn_softcap,
-                                          constrain_q=cfg.pos != "mrope")
+        def attend(qq, kk, vv, lengths):
+            return A.decode_attention(qq, kk, vv, lengths, scale=scale,
+                                      softcap=cfg.attn_softcap,
+                                      constrain_q=cfg.pos != "mrope")
+        if pages is None:
+            out = attend(q, _layer_of(kc, layer), _layer_of(vc, layer),
+                         bpos + 1)
+        elif s == 1:
+            # Paged: a dense logical view gathered through the page table
+            # goes through the slot path's attention unchanged -- masked
+            # positions contribute exact zeros, so a single-token step is
+            # bit-identical to the slot cache's (tests/test_serve_paging.py
+            # pins it).
+            bpos = _decode_batch_pos(cfg, positions)
             if mm.current_backend() == "pallas":
                 # fuse the page-table gather into the attention pass
-                # (scatter stays outside: the pools are the carried state)
+                # (scatter stays outside: the pools are the carried
+                # state); the kernel takes [P, ps, kv, hd] pools
                 from repro.kernels.zvg_matmul.fused import (
                     fused_paged_attention)
-                out = fused_paged_attention(q, kp, vp, pages, bpos + 1,
-                                            attend)
+
+                def heads(pool):
+                    pool = _layer_of(pool, layer)
+                    return pool.reshape(pool.shape[:2] + (kv, hd))
+                out = fused_paged_attention(q, heads(kc), heads(vc), pages,
+                                            bpos + 1, attend)
             else:
-                out = attend(q, _gather_pages(kp, pages),
-                             _gather_pages(vp, pages), bpos + 1)
+                out = attend(q, _gather_pages(kc, pages, layer),
+                             _gather_pages(vc, pages, layer), bpos + 1)
         else:                                     # chunked prefill
-            kc = _gather_pages(kp, pages)
-            vc = _gather_pages(vp, pages)
-            out = A.paged_chunk_attention(q, kc, vc, positions, scale=scale,
+            kk, vv = (_gather_pages(c, pages, layer).reshape(b, -1, kv, hd)
+                      for c in (kc, vc))
+            out = A.paged_chunk_attention(q, kk, vv, positions, scale=scale,
                                           softcap=cfg.attn_softcap,
                                           constrain_q=cfg.pos != "mrope")
         out = out.reshape(b, s, h * hd)
         with jax.named_scope("wo"):
             out = mm.matmul(out, p["wo"].value.astype(x.dtype))
-        return out, (kp, vp)
+        return out, (kc, vc)
 
-    if state is not None:                       # ---- single-token decode
+    if state is not None:                       # ---- ring-buffer decode
         bpos = _decode_batch_pos(cfg, positions)
         rows = jnp.arange(b)
-        if local:
-            kc, vc, slots = state
-            w_sz = kc.shape[1]
-            slot = bpos % w_sz
-            kc = kc.at[rows, slot].set(k[:, 0].astype(kc.dtype))
-            vc = vc.at[rows, slot].set(v[:, 0].astype(vc.dtype))
-            slots = slots.at[rows, slot].set(bpos.astype(slots.dtype))
-            out = _ring_decode(q, kc, vc, slots, bpos, cfg, scale)
-            new_state = (kc, vc, slots)
-        else:
-            kc, vc = state
-            kc = kc.at[rows, bpos].set(k[:, 0].astype(kc.dtype))
-            vc = vc.at[rows, bpos].set(v[:, 0].astype(vc.dtype))
-            out = A.decode_attention(q, kc, vc, bpos + 1, scale=scale,
-                                     softcap=cfg.attn_softcap,
-                                     constrain_q=cfg.pos != "mrope")
-            new_state = (kc, vc)
+        kc, vc, slots = state
+        w_sz = kc.shape[1]
+        slot = bpos % w_sz
+        kc = kc.at[rows, slot].set(k[:, 0].astype(kc.dtype))
+        vc = vc.at[rows, slot].set(v[:, 0].astype(vc.dtype))
+        slots = slots.at[rows, slot].set(bpos.astype(slots.dtype))
+        out = _ring_decode(q, kc, vc, slots, bpos, cfg, scale)
         out = out.reshape(b, s, h * hd)
         with jax.named_scope("wo"):
             out = mm.matmul(out, p["wo"].value.astype(x.dtype))
-        return out, new_state
+        return out, (kc, vc, slots)
 
     if local:                                   # ---- parallel
         out = A.sliding_window_attention(q, k, v, window=cfg.window,
@@ -279,10 +300,9 @@ def apply_attention(p, x, cfg: ArchConfig, *, local: bool, positions,
                 jnp.broadcast_to(t, (b, take)))
             new_state = (kc, vc, slots)
         else:
-            pad = cache_len - s
-            kc = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            vc = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-            new_state = (kc, vc)
+            pad = ((0, 0), (0, cache_len - s), (0, 0))
+            new_state = (jnp.pad(k.reshape(b, s, kv * hd), pad),
+                         jnp.pad(v.reshape(b, s, kv * hd), pad))
     return out, new_state
 
 
@@ -380,7 +400,8 @@ def apply_mla(p, x, cfg: ArchConfig, *, positions, state=None,
 
 # ------------------------------------------------------------------- mixers
 def apply_mixer(p, x, cfg: ArchConfig, mixer: str, *, positions,
-                state=None, prefill=False, cache_len=0, pages=None):
+                state=None, prefill=False, cache_len=0, pages=None,
+                layer=None):
     if pages is not None and mixer not in ("attn", "mla"):
         raise ValueError(
             f"paged decode supports position-masked cache mixers "
@@ -389,7 +410,7 @@ def apply_mixer(p, x, cfg: ArchConfig, mixer: str, *, positions,
         return apply_attention(p, x, cfg, local=(mixer == "local"),
                                positions=positions, state=state,
                                prefill=prefill, cache_len=cache_len,
-                               pages=pages)
+                               pages=pages, layer=layer)
     if mixer == "mla":
         return apply_mla(p, x, cfg, positions=positions, state=state,
                          prefill=prefill, cache_len=cache_len, pages=pages)
@@ -477,7 +498,7 @@ def _slstm_decode(p, x, cfg, conv_buf, cell):
 # -------------------------------------------------------------------- block
 def apply_block(p, x, cfg: ArchConfig, spec: str, *, positions,
                 state=None, prefill=False, cache_len=0,
-                constrain=lambda a: a, pages=None):
+                constrain=lambda a: a, pages=None, layer=None):
     mixer, ffn = parse_spec(spec)
     aux = jnp.zeros((), jnp.float32)
     h = L.apply_norm(cfg.norm, p["norm1"], x)
@@ -488,7 +509,7 @@ def apply_block(p, x, cfg: ArchConfig, spec: str, *, positions,
         out, new_state = apply_mixer(p["mixer"], h, cfg, mixer,
                                      positions=positions, state=state,
                                      prefill=prefill, cache_len=cache_len,
-                                     pages=pages)
+                                     pages=pages, layer=layer)
     # constraining each residual add to the SP layout lets GSPMD lower the
     # row-parallel output reductions to reduce-scatters (see §Perf cell B)
     x = constrain(x + cfg.resid_mult * out)
@@ -504,6 +525,13 @@ def apply_block(p, x, cfg: ArchConfig, spec: str, *, positions,
 
 
 # -------------------------------------------------------------------- stack
+def _writes_in_place(spec: str) -> bool:
+    """Whether a scanned block's decode cache is written in place in the
+    stacked ``[G, ...]`` carry (full attention, see ``apply_attention``)
+    instead of being sliced out and written back."""
+    return parse_spec(spec)[0] == "attn"
+
+
 def make_stack(key, cfg: ArchConfig) -> dict:
     """Stacked group params [G, ...] + unrolled tail params."""
     def group_init(k):
@@ -528,7 +556,7 @@ def apply_stack(params, x, cfg: ArchConfig, *, positions, states=None,
     """Run all layers. Returns (x, new_states | None, aux_sum)."""
     decode = states is not None
 
-    def group_body(x, gparams, gstate):
+    def group_body(x, gparams, gstate, layer=None):
         new_states = {}
         aux_sum = jnp.zeros((), jnp.float32)
         for i, spec in enumerate(cfg.pattern):
@@ -537,7 +565,8 @@ def apply_stack(params, x, cfg: ArchConfig, *, positions, states=None,
                 x, nst, aux = apply_block(
                     gparams[f"b{i}"], x, cfg, spec, positions=positions,
                     state=st, prefill=prefill, cache_len=cache_len,
-                    pages=pages)
+                    pages=pages,
+                    layer=layer if _writes_in_place(spec) else None)
             new_states[f"b{i}"] = nst
             aux_sum = aux_sum + aux
         x = constrain(x)
@@ -563,20 +592,28 @@ def apply_stack(params, x, cfg: ArchConfig, *, positions, states=None,
     x = constrain(x)
     if cfg.scan_layers:
         if decode:
-            # keep the stacked per-layer states in the scan CARRY with
-            # dynamic in-place slice updates: XLA aliases the carry across
-            # iterations, so the (large) KV caches never pass through the
-            # scan's xs/ys double buffers (§Perf cell A)
+            # keep the stacked per-layer states in the scan CARRY, so the
+            # (large) KV caches never pass through the scan's xs/ys double
+            # buffers. Full-attention blocks take the whole stacked cache
+            # and the layer index and scatter their rows into it in place
+            # (apply_attention): a sliced-out layer of a cache whose minor
+            # dim is hd = 64, half a 128-lane tile, cost a relayout copy
+            # in and out plus a whole-slice write-back every step. Other
+            # states are sliced out and written back in place.
+            stacked = {f"b{i}" for i, spec in enumerate(cfg.pattern)
+                       if _writes_in_place(spec)}
+
             def scan_fn(carry, inp):
                 x, aux_acc, all_states = carry
                 gparams, gi = inp
-                gstate = jax.tree.map(
-                    lambda a: jax.lax.dynamic_index_in_dim(
-                        a, gi, 0, keepdims=False), all_states)
-                x, nst, aux = body(x, gparams, gstate)
-                all_states = jax.tree.map(
+                gstate = {n: st if n in stacked else jax.tree.map(
+                    lambda a: _layer_of(a, gi), st)
+                    for n, st in all_states.items()}
+                x, nst, aux = body(x, gparams, gstate, gi)
+                all_states = {n: nst[n] if n in stacked else jax.tree.map(
                     lambda a, u: jax.lax.dynamic_update_index_in_dim(
-                        a, u.astype(a.dtype), gi, 0), all_states, nst)
+                        a, u.astype(a.dtype), gi, 0), st, nst[n])
+                    for n, st in all_states.items()}
                 return (x, aux_acc + aux, all_states), None
             (x, aux_total, new_gstates), _ = jax.lax.scan(
                 scan_fn, (x, head_aux, states["groups"]),

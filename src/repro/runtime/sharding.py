@@ -156,13 +156,15 @@ def cache_shardings(mesh: Mesh, states) -> Any:
     contract: ``head`` / ``tail`` leaves are ``[B, ...]`` while scanned
     ``groups`` leaves are ``[G, B, ...]`` (the scan axis is never
     sharded), so the TRUE batch dim -- the slot axis -- shards over the
-    data axes. One trailing feature dim (kv heads / head dim / lora rank)
-    shards over "model", matching the TP layout the serve rules give the
-    attention weights; the dim right after the slot axis is the cache
-    sequence/window dim and is never given to "model" (sharding it would
-    force an all-gather inside every decode step). Every assignment is
-    divisibility-guarded: awkward slot counts or head counts fall back to
-    replication, never to a compile error.
+    data axes. One trailing feature dim (the lane-dense ``kv * hd`` of
+    full-attention K/V, a ring cache's head dim, an MLA lora rank) shards
+    over "model", matching the TP layout the serve rules give the
+    attention weights; on the flat K/V dim each shard holds whole KV
+    heads when "model" divides kv. The dim right after the slot axis is
+    the cache sequence/window dim and is never given to "model" (sharding
+    it would force an all-gather inside every decode step). Every
+    assignment is divisibility-guarded: awkward slot counts or head
+    counts fall back to replication, never to a compile error.
     """
     sizes = _mesh_axes(mesh)
     dpx = dp_axes(mesh)

@@ -12,6 +12,12 @@ the full-row overwrite at the next admission).
 Leaf layout note: scanned group states are stacked ``[G, B, ...]`` while
 head/tail block states are ``[B, ...]``, so the scatter runs per top-level
 key with the right batch axis (1 vs 0) rather than one uniform tree_map.
+Full-attention K/V leaves are lane-dense ``[B, T, kv * hd]`` (``[G, B, T,
+kv * hd]`` stacked): with hd = 64 a ``[.., kv, hd]`` leaf fills half a
+128-lane tile and the compiler relays it out, so the prefill produces
+the flat rows this scatter installs, the decode step writes its rows
+into the stack in place and its attention reads them in that layout
+(``lm.make_decode_state``).
 
 Mesh mode: constructed with a ``Mesh``, the shared states live as
 ``runtime.sharding.cache_shardings`` NamedShardings (slot axis over the

@@ -3,8 +3,11 @@
 The pool reuses ``lm.make_decode_state`` with ``batch = num_pages`` and
 ``cache_len = page_size``: every leaf is ``[P, page_size, ...]`` (scanned
 groups ``[G, P, page_size, ...]``), i.e. the slot cache's layout with the
-page axis in the slot axis's role. A request's logical position ``p``
-lives at physical ``(table[p // page_size], p % page_size)``; the decode
+page axis in the slot axis's role. Full-attention K/V pools are
+lane-dense ``[P, page_size, kv * hd]`` like the slot cache's rows (see
+``serve.cache``); the Pallas paged kernel gets a ``[P, page_size, kv,
+hd]`` view of them at its call. A request's logical position ``p`` lives
+at physical ``(table[p // page_size], p % page_size)``; the decode
 step carries the table as a ``[B, MP]`` input and the model scatters
 writes / gathers dense logical views through it (see
 ``models.transformer._page_targets`` / ``_gather_pages``).

@@ -122,12 +122,18 @@ def test_cache_shardings_slot_axis_and_features():
         assert spec[1] == "data"            # slot axis (4 % 2 == 0)
         if leaf.ndim >= 4:
             assert spec[2] is None          # cache sequence axis
+    # attention K/V are lane-dense [G, B, T, kv * hd]: the flat feature
+    # dim takes "model" (2 divides kv = 4, so each shard holds whole heads)
+    for leaf, ns in zip(states["groups"]["b0"],
+                        shardings["groups"]["b0"]):
+        assert leaf.shape == (cfg.n_groups, 4, 32, cfg.n_kv_heads * cfg.hd)
+        assert tuple(ns.spec) == (None, "data", None, "model")
 
 
 def test_cache_shardings_divisibility_fallback():
     cfg = SMOKES["qwen1.5-0.5b"].with_(compute_dtype="float32")
-    # 3 slots over data=2: slot axis replicates; kv heads (4) over
-    # model=8: indivisible, the head dim (16) takes "model" instead
+    # 3 slots over data=2: slot axis replicates; the flat K/V feature dim
+    # (kv 4 x hd 16 = 64) over model=8 splits heads but divides
     states = jax.eval_shape(
         lambda: lm.make_decode_state(cfg, 3, 32, dtype=np.float32))
     # (a size-1 "data" axis always divides and shards nothing)

@@ -61,6 +61,30 @@ def test_decode_matches_full_last_position():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("h,kv", [(4, 4), (8, 2)])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 1.5e-2)])
+def test_decode_lane_dense_matches_f32_reference(h, kv, dtype, tol):
+    """Decode attention over lane-dense ``[B, T, kv * hd]`` caches against
+    the float32 reference over each row's live positions alone: row 0
+    sits at position 0, row 1 at the horizon's last position, and every
+    position past a row's length holds large junk the mask must drop."""
+    b, t, hd = 3, 24, 16
+    q, k, v = _qkv(b=b, s=t, h=h, kv=kv, d=hd)
+    lengths = np.array([1, t, 11], np.int32)
+    live = np.arange(t)[None, :, None, None] < lengths[:, None, None, None]
+    kc = jnp.where(live, k, 1e3).reshape(b, t, kv * hd)
+    vc = jnp.where(live, v, -1e3).reshape(b, t, kv * hd)
+    got = A.decode_attention(q[:, -1:].astype(dtype), kc.astype(dtype),
+                             vc.astype(dtype), jnp.asarray(lengths))
+    assert got.shape == (b, 1, h, hd) and got.dtype == dtype
+    for i, n in enumerate(lengths):
+        want = A.full_attention(q[i:i + 1, -1:], k[i:i + 1, :n],
+                                v[i:i + 1, :n], causal=False)
+        np.testing.assert_allclose(np.asarray(got[i:i + 1], np.float32),
+                                   np.asarray(want), rtol=tol, atol=tol)
+
+
 def test_softcap_applied():
     q, k, v = _qkv()
     a = A.full_attention(q * 50, k * 50, v, causal=True)

@@ -9,20 +9,31 @@ can, at no chip time. Nothing runs, so results are checked elsewhere
 (``tests/test_power_counter_kernels.py`` in interpret mode, and
 ``chip_smoke.py`` on the chip).
 
+The decode step is compiled here too, the way the serving engine jits
+it, to check the layout of its KV cache in the optimized program: what
+the TPU compiler does with a cache layout shows only in a compile for a
+TPU.
+
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+
+import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.qwen1_5_0_5b import CONFIG as QWEN
 from repro.core import bic, monitor
 from repro.design.evaluate import menu_args
 from repro.kernels.power_counters.kernel import fused_counters_pallas
 from repro.kernels.power_counters.spec import CounterSpec
+from repro.models import lm
+from repro.models import matmul as mm
 
 #: qwen1.5-0.5b's d_model (the K of both monitored layer-0 weights) and
 #: the decode batch chip_smoke.py serves
@@ -98,3 +109,54 @@ def test_counter_kernel_compiles_for_v5e(case, one_chip):
     x = jax.ShapeDtypeStruct(shape, jnp.uint16, sharding=one_chip)
     compiled = jax.jit(fn).lower(x).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+#: the serving benchmark's slot cache: 32 slots of 2048 positions
+SLOTS, HORIZON = 32, 2048
+
+#: an HLO instruction's result shape and opcode, fused or not
+_INSTR = re.compile(r"= \w+\[([\d,]*)\]\{[^}]*\} ([\w-]+)\(")
+
+
+def _big_moves(hlo: str, min_elems: int) -> list[str]:
+    """Every copy and dynamic-update-slice of the program, top level or
+    inside a fusion, whose result holds at least ``min_elems`` elements."""
+    out = []
+    for line in hlo.splitlines():
+        m = _INSTR.search(line)
+        if m is None or m.group(2) not in (
+                "copy", "copy-start", "dynamic-update-slice"):
+            continue
+        n = 1
+        for dim in filter(None, m.group(1).split(",")):
+            n *= int(dim)
+        if n >= min_elems:
+            out.append(line.strip()[:160])
+    return out
+
+
+def test_decode_step_keeps_kv_cache_in_place(one_chip):
+    """qwen1.5-0.5b's decode step at full width (two layers), jitted as
+    the serving engine jits it (the ``ref`` backend bound by
+    ``functools.partial``, the cache donated), neither copies a layer's
+    K or V cache nor writes a whole layer slice back into the stack: the
+    step's rows are scattered into the stacked cache in place and the
+    attention reads the layer's slice inside its products."""
+    cfg = QWEN.with_(n_layers=2)
+
+    def spec(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = spec(jax.eval_shape(
+        lambda: lm.init_model(jax.random.key(0), cfg)))
+    states = spec(jax.eval_shape(
+        lambda: lm.make_decode_state(cfg, SLOTS, HORIZON)))
+    col = jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32, sharding=one_chip)
+    step = jax.jit(functools.partial(mm.with_backend, "ref",
+                                     lm.make_decode_step(cfg)),
+                   donate_argnums=(1,))
+    hlo = step.lower(params, states,
+                     {"tokens": col, "positions": col}).compile().as_text()
+    layer = SLOTS * HORIZON * cfg.n_kv_heads * cfg.hd
+    assert _big_moves(hlo, layer) == []
