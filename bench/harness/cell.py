@@ -14,7 +14,6 @@ import types
 import jax
 import numpy as np
 
-from bench import counts
 from bench.harness import correct, e2e, spec, system, trace, weights
 from bench.harness.loop import Loop, Spans
 from repro.serve import SamplingParams, ServeEngine
@@ -129,11 +128,11 @@ def _window_counts(loop, inst, stats0, stats1, w0, w1) -> dict:
             "accountant_s": inst.acc_timer.seconds}
 
 
-def _decode_tokens_flops(cfg: dict, decode_log) -> float:
+def _decode_tokens_flops(family, cfg: dict, decode_log) -> float:
     """Model FLOPs of the window's decode steps from each step's live
-    rows and their attended positions."""
-    per_tok = counts.token_flops(cfg, 0, True)
-    att = counts.token_flops(cfg, 1, False) - counts.token_flops(cfg, 0,
+    rows and their attended positions (the family's counts)."""
+    per_tok = family.token_flops(cfg, 0, True)
+    att = family.token_flops(cfg, 1, False) - family.token_flops(cfg, 0,
                                                                  False)
     return sum(rows * per_tok + att * pos for rows, pos in decode_log)
 
@@ -145,13 +144,13 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     dev = device_info(cell.chips, require_tpu)
     enable_cache(root)
     clock = CompileClock().install()
-    cfg, traffic = cell.config, cell.traffic
+    cfg, traffic, family = cell.config, cell.traffic, cell.family
     # off the chip (the CPU tests of this harness) the arithmetic runs
     # against the v5e's peaks; run.py never gets here without a TPU
     peaks = peaks_for(dev["kind"] if require_tpu else "TPU v5 lite",
                       cell.bench_dir)
 
-    arch = system.arch_config(cfg)
+    arch = family.arch_config(cfg)
     scfg = system.serve_config(cfg, traffic, seed)
     params = weights.for_config(system.init_fn(arch), seed, cfg)
     jax.block_until_ready(params)
@@ -242,9 +241,9 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
         dev["window_s"] = (t1 - t0) * 1e-9
         rctx = types.SimpleNamespace(
             trace=tr, t0=t0, t1=t1, window_s=(t1 - t0) * 1e-9,
-            busy_s=busy * 1e-9, config=cfg, counts=counts, peaks=peaks,
+            busy_s=busy * 1e-9, config=cfg, counts=family, peaks=peaks,
             window=wc, monitored=monitored, engine_mode=cfg["engine"]["mode"],
-            decode_flops=_decode_tokens_flops(cfg, wc["decode_log"]))
+            decode_flops=_decode_tokens_flops(family, cfg, wc["decode_log"]))
         for m in cell.per_layer:
             v = spec.load_metric(m["name"], cell.bench_dir).read(rctx)
             if v is not None:
@@ -257,17 +256,18 @@ def run(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     # ------------------------------------------------------- correctness
     system.free_engine(engine)
     sample = correct.sample_tracks(loop.tracks, w0, w1, seed)
-    plain = weights.plain_weights(params)
+    plain = family.plain_weights(params, cfg)
     t_ref = time.perf_counter()
     c_ref = clock.compiles
-    gaps = (correct.gap_readings(plain, cfg, sample, scfg.cache_len, control)
+    gaps = (correct.gap_readings(cell.reference, plain, cfg, sample,
+                                 scfg.cache_len, control)
             if sample else {})
     numbers = dict(gaps.get("served") or
                    {"logit_gap": None, "logit_gap_mean": None})
     t_gap = time.perf_counter()
     if monitored:
         numbers["energy_rel_err"] = (correct.energy_rel_err(
-            plain, cfg, sample, inst.prefill_log, scfg.cache_len)
+            family, plain, cfg, sample, inst.prefill_log, scfg.cache_len)
             if sample else None)
         t_energy = time.perf_counter()
         numbers["counter_mismatch"] = (

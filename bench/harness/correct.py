@@ -1,6 +1,9 @@
 """What decides ``correct``: the served tokens, the accountant's integer
 counters and its per-request energies, each against a plain reference
-that takes only the benchmark's weights and the requests' tokens.
+that takes only the benchmark's weights and the requests' tokens. The
+served tokens are judged by the configuration's family's float32
+reference (``bench/reference/<family>.py``), the energies at the sites
+the family names (``monitor_sites``).
 
 Numbers read (each compared where the configuration file gives it a
 limit):
@@ -26,7 +29,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench.reference import accountant as ref_acc
-from bench.reference import model as ref_model
 
 SAMPLE = 8
 
@@ -67,17 +69,18 @@ def _embed_bits(embed, tokens, mult: float, pad_to: int) -> np.ndarray:
     return x.astype(jnp.bfloat16).view(np.uint16)
 
 
-def gap_readings(weights, cfg: dict, sample, pad_to: int,
+def gap_readings(reference, weights, cfg: dict, sample, pad_to: int,
                  control: bool = False) -> dict:
     """``{"served": {"logit_gap": widest, "logit_gap_mean": mean}}`` over
-    every served token of the sample; with ``control`` the same for the
-    tokens the float8 control (``"control"``) and the bfloat16 witness
-    (``"witness"``) put first at the same positions."""
+    every served token of the sample, judged by the ``reference``
+    module; with ``control`` the same for the tokens the float8 control
+    (``"control"``) and the bfloat16 witness (``"witness"``) put first
+    at the same positions."""
     per: dict[str, list] = {}
     for t in sample:
         seq = t.req.prompt + t.req.generated
-        g = ref_model.token_gaps(weights, cfg, seq, t.req.prompt_len, pad_to,
-                                 control=control)
+        g = reference.token_gaps(weights, cfg, seq, t.req.prompt_len,
+                                 pad_to, control=control)
         for k, v in g.items():
             per.setdefault(k, []).append(v)
     out = {}
@@ -88,12 +91,10 @@ def gap_readings(weights, cfg: dict, sample, pad_to: int,
     return out
 
 
-def energy_rel_err(weights, cfg: dict, sample, prefill_log,
+def energy_rel_err(family, weights, cfg: dict, sample, prefill_log,
                    pad_to: int) -> float:
-    layers = weights["layers"]
-    sites = [ref_acc.SiteRef(_bits(layers["wq"][0])),
-             ref_acc.SiteRef(_bits(layers["up"][0]))]
-    mult = float(cfg.get("embedding_multiplier", 1.0))
+    sites = [ref_acc.SiteRef(_bits(w)) for w in family.monitor_sites(weights)]
+    mult = family.embed_multiplier(cfg)
     worst = 0.0
     for t in sample:
         req = t.req

@@ -1,11 +1,14 @@
 """``BENCHMARK.json`` and the files it names, resolved by name.
 
 A cell names a configuration and a traffic mix; the configuration's
-file is the one ``configs`` lists for it, the mix is
+file is the one ``configs`` lists for it, and its ``family`` names
+``bench/families/<family>.py`` (the program side of the architecture)
+and ``bench/reference/<family>.py`` (its float32 reference); the mix is
 ``bench/traffic/<traffic>.json`` with its generator in
 ``bench/traffic/kinds/<kind>.py``, and each per-layer metric is read by
 ``bench/metrics/<name>.py``. Nothing here knows a cell by name: adding a
-cell, a mix or a metric is adding files and entries.
+cell, a mix, a metric or an architecture family is adding files and
+entries.
 """
 from __future__ import annotations
 
@@ -16,6 +19,7 @@ import json
 import os
 import re
 import sys
+import types
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -56,6 +60,8 @@ class Cell:
     end_to_end: list[dict]
     per_layer: list[dict]
     bench_dir: str
+    family: types.ModuleType
+    reference: types.ModuleType
 
     def load_traffic(self, name: str) -> dict:
         return load_json(os.path.join(self.bench_dir, "traffic",
@@ -63,6 +69,10 @@ class Cell:
 
 
 def _load_file(path: str, modname: str):
+    """The module of ``path``, executed once per process and path."""
+    mod = sys.modules.get(modname)
+    if mod is not None and getattr(mod, "__file__", None) == path:
+        return mod
     spec = importlib.util.spec_from_file_location(modname, path)
     mod = importlib.util.module_from_spec(spec)
     sys.modules[modname] = mod
@@ -90,6 +100,22 @@ def load_metric(name: str, bench_dir: str = BENCH_DIR):
                       f"bench_metric_{name}")
 
 
+def family_files(family: str, bench_dir: str = BENCH_DIR
+                 ) -> tuple[str, str]:
+    """The program-side module and the reference module of a family."""
+    name = check_name(family) + ".py"
+    return (os.path.join(bench_dir, "families", name),
+            os.path.join(bench_dir, "reference", name))
+
+
+def load_family(family: str, bench_dir: str = BENCH_DIR
+                ) -> tuple[types.ModuleType, types.ModuleType]:
+    """``(family module, reference module)`` of an architecture family."""
+    prog, ref = family_files(family, bench_dir)
+    return (_load_file(prog, f"bench_family_{family}"),
+            _load_file(ref, f"bench_reference_{family}"))
+
+
 def _applies(metric: dict, cell: str) -> bool:
     return "workloads" not in metric or cell in metric["workloads"]
 
@@ -109,8 +135,9 @@ def resolve(bench: dict, cell_name: str, root: str = ROOT) -> Cell:
     moved = {m["name"] for m in e2e}
     per_layer = [m for m in bench["per_layer"]
                  if _applies(m, cell_name) and m["moves"] in moved]
+    family, reference = load_family(config["family"], bench_dir)
     return Cell(cell_name, config, traffic, int(w["chips"]),
-                e2e, per_layer, bench_dir)
+                e2e, per_layer, bench_dir, family, reference)
 
 
 def validate(bench: dict, root: str = ROOT) -> list[str]:
@@ -140,6 +167,18 @@ def validate(bench: dict, root: str = ROOT) -> list[str]:
     for c in bench["configs"]:
         path = os.path.join(root, c["file"])
         need(os.path.isfile(path), f"missing config file {c['file']}")
+        family = (load_json(path).get("family")
+                  if os.path.isfile(path) else None)
+        need(family is not None, f"{c['name']}: no family")
+        if family is not None:
+            try:
+                files = family_files(family, os.path.join(root, "bench"))
+            except ValueError as e:
+                errs.append(f"{c['name']}: family: {e}")
+                files = ()
+            for f in files:
+                need(os.path.isfile(f), f"{c['name']}: family {family!r} "
+                     f"has no {os.path.relpath(f, root)}")
         for k in c["reduced"]:
             try:
                 check_name(k)

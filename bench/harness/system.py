@@ -15,37 +15,10 @@ import time
 import jax
 import numpy as np
 
-from repro.configs import ARCHS
 from repro.models import lm
 from repro.serve import ServeConfig
 from repro.serve import power as serve_power
 from repro.serve.paging import PagingConfig
-
-
-def arch_config(cfg: dict):
-    """The program's ArchConfig for a configuration file: the registry
-    entry named by ``arch`` with every size taken from the file, so the
-    file is what runs. Dense attention + gated-MLP family only."""
-    if cfg["rms_norm_eps"] != 1e-6:
-        raise ValueError("the program's RMSNorm epsilon is fixed at 1e-6; "
-                         "the configuration file must state what runs")
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    kw = dict(
-        n_layers=cfg["num_hidden_layers"], d_model=d, n_heads=h,
-        n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab=cfg["vocab_size"],
-        qkv_bias=bool(cfg.get("qkv_bias") or cfg.get("attention_bias")),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        rope_theta=float(cfg["rope_theta"]),
-        emb_mult=float(cfg.get("embedding_multiplier", 1.0)),
-        resid_mult=float(cfg.get("residual_multiplier", 1.0)),
-        attn_scale=float(cfg.get("attention_multiplier", 0.0)),
-        logit_mult=1.0 / float(cfg.get("logits_scaling", 1.0)),
-        head_dim=int(cfg.get("head_dim", 0)),
-        pattern=("attn+mlp",), compute_dtype="bfloat16")
-    if "attn_block_k" in cfg:
-        kw["attn_block_k"] = int(cfg["attn_block_k"])
-    return ARCHS[cfg["arch"]].with_(**kw)
 
 
 def serve_config(cfg: dict, traffic: dict, seed: int) -> ServeConfig:

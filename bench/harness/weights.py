@@ -3,7 +3,8 @@
 The program's own parameter tree gives only the STRUCTURE (names and
 shapes, from ``jax.eval_shape`` of its initializer); every value comes
 from one jitted call here, so the same seed gives the same weights, and
-the reference reads the very arrays the program serves with.
+the reference reads the very arrays the program serves with (laid out
+for it by the family's ``plain_weights``, ``bench/families/``).
 
 Rules by leaf name: every matrix and the attention biases N(0, std^2),
 the embedding N(0, embed_std^2), norm scales 1 + N(0, 0.1^2); the two
@@ -61,18 +62,3 @@ def for_config(init_fn, seed: int, cfg: dict):
     w = cfg["weights"]
     return make_params(init_fn, seed, float(w["std"]), float(w["embed_std"]))
 
-
-def plain_weights(params) -> dict:
-    """The program's tree as the reference's plain layout: ``embed``,
-    ``final_norm`` and per-layer arrays stacked on axis 0 (the program
-    scans one block per group, ``pattern == ("attn+mlp",)``)."""
-    val = lambda p: p.value
-    blk = params["stack"]["groups"]["b0"]
-    mix, ffn = blk["mixer"], blk["ffn"]
-    layers = {name: val(mix[name]) for name in mix}
-    layers.update({name: val(ffn[name]) for name in ffn})
-    layers["norm1"] = val(blk["norm1"]["scale"])
-    layers["norm2"] = val(blk["norm2"]["scale"])
-    return {"embed": val(params["embed"]),
-            "final_norm": val(params["final_norm"]["scale"]),
-            "layers": layers}

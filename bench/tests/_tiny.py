@@ -38,7 +38,12 @@ def _with_unlisted(bench: dict, name: str) -> dict:
 
 
 def tiny_cell(name: str) -> spec.Cell:
-    c = spec.resolve(_with_unlisted(spec.load_benchmark(), name), name)
+    return shrink(spec.resolve(_with_unlisted(spec.load_benchmark(), name),
+                               name))
+
+
+def shrink(c: spec.Cell) -> spec.Cell:
+    """A resolved cell cut to CPU size, in place."""
     cfg = c.config
     cfg.update(TINY)
     cfg["num_key_value_heads"] = 4 if cfg["model_type"] == "qwen2" else 2

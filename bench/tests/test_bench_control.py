@@ -16,7 +16,6 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 
 from bench.harness import correct, spec, system, weights  # noqa: E402
-from bench.reference import model as ref_model  # noqa: E402
 
 
 @pytest.mark.parametrize("name", ["qwen1.5-0.5b", "granite-3-2b"])
@@ -24,9 +23,10 @@ def test_float8_control_is_not_correct(name):
     cfg = spec.load_json(os.path.join(spec.BENCH_DIR, "configs",
                                       name + ".json"))
     cfg.update(num_hidden_layers=8)
-    arch = system.arch_config(cfg)
+    fam, ref_model = spec.load_family(cfg["family"])
+    arch = fam.arch_config(cfg)
     params = weights.for_config(system.init_fn(arch), 2 ** 31 + 21, cfg)
-    w = weights.plain_weights(params)
+    w = fam.plain_weights(params, cfg)
     seq = [int(t) for t in np.random.default_rng(4).integers(
         0, cfg["vocab_size"], 256)]
     gaps = ref_model.token_gaps(w, cfg, seq, 8, 256, control=True)

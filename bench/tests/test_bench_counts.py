@@ -1,4 +1,6 @@
-"""Work counts pinned to hand-worked numbers, and the peaks table."""
+"""Work counts pinned to hand-worked numbers, read through the dense
+family (``bench/families/dense.py``) the configuration files name, and
+the peaks table."""
 import os
 import sys
 
@@ -7,7 +9,6 @@ import _paths  # noqa: E402,F401
 
 import pytest  # noqa: E402
 
-from bench import counts  # noqa: E402
 from bench.harness import cell, spec  # noqa: E402
 
 
@@ -16,6 +17,10 @@ def _cfg(name, **kw):
                                     name + ".json"))
     c.update(kw)
     return c
+
+
+counts, _ = spec.load_family(_cfg("qwen1.5-0.5b")["family"])
+assert counts is spec.load_family(_cfg("granite-3-2b")["family"])[0]
 
 
 def test_qwen_counts():

@@ -21,7 +21,8 @@ def root(tmp_path_factory):
 
 
 #: seconds of window in which each tiny cell finishes greedy requests
-WINDOW = {CELL: 1.0, "granite2b.sessions.monitored": 3.0}
+WINDOW = {CELL: 1.0, "qwen05b.offline.plain": 1.0,
+          "granite2b.sessions.monitored": 3.0}
 
 
 @pytest.mark.parametrize("cell", list(WINDOW))

@@ -15,7 +15,6 @@ import pytest  # noqa: E402
 
 from bench.harness import spec, system, weights  # noqa: E402
 from bench.reference import accountant as ref_acc  # noqa: E402
-from bench.reference import model as ref_model  # noqa: E402
 
 
 def tiny(name, **kw):
@@ -28,17 +27,23 @@ def tiny(name, **kw):
     return c
 
 
+def family(cfg):
+    """``(family module, reference module)`` the configuration names."""
+    return spec.load_family(cfg["family"])
+
+
 @pytest.mark.parametrize("name", ["qwen1.5-0.5b", "granite-3-2b"])
 def test_forward_matches_program_in_float32(name):
     from repro.models import lm
     cfg = tiny(name)
-    arch = system.arch_config(cfg).with_(compute_dtype="float32")
+    fam, ref_model = family(cfg)
+    arch = fam.arch_config(cfg).with_(compute_dtype="float32")
     params = weights.for_config(system.init_fn(arch), 2 ** 31 + 3, cfg)
     toks = jnp.asarray(np.random.default_rng(0).integers(0, 256, (1, 24)))
     h, _, _ = lm.apply_model(params, arch, {"tokens": toks})
     with jax.default_matmul_precision("highest"):
         want = lm.logits_fn(params, arch, h)[0]
-    got = ref_model.forward(weights.plain_weights(params), cfg, toks[0])
+    got = ref_model.forward(fam.plain_weights(params, cfg), cfg, toks[0])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4 * float(
                                    jnp.abs(want).max()))
@@ -46,9 +51,10 @@ def test_forward_matches_program_in_float32(name):
 
 def test_gaps_pick_the_served_token():
     cfg = tiny("qwen1.5-0.5b")
-    arch = system.arch_config(cfg)
+    fam, ref_model = family(cfg)
+    arch = fam.arch_config(cfg)
     params = weights.for_config(system.init_fn(arch), 5, cfg)
-    w = weights.plain_weights(params)
+    w = fam.plain_weights(params, cfg)
     seq = list(np.random.default_rng(1).integers(0, 256, 10))
     logits = np.asarray(ref_model.forward(w, cfg, jnp.asarray(seq)))
     best = list(seq[:6]) + [int(logits[5].argmax())]

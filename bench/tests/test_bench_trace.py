@@ -11,7 +11,6 @@ import _paths  # noqa: E402,F401
 
 import pytest  # noqa: E402
 
-from bench import counts  # noqa: E402
 from bench.harness import cell, spec, trace  # noqa: E402
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
@@ -21,6 +20,13 @@ DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data",
 @pytest.fixture(scope="module")
 def tr():
     return trace.load(DATA)
+
+
+def _qwen():
+    """The qwen configuration and its family, the readers' ``counts``."""
+    cfg = spec.load_json(os.path.join(spec.BENCH_DIR, "configs",
+                                      "qwen1.5-0.5b.json"))
+    return cfg, spec.load_family(cfg["family"])[0]
 
 
 def test_union_and_busy_on_synthetic_intervals():
@@ -65,8 +71,7 @@ def test_programs_by_stable_name(tr):
 
 
 def test_readers_on_the_recorded_trace(tr):
-    cfg = spec.load_json(os.path.join(spec.BENCH_DIR, "configs",
-                                      "qwen1.5-0.5b.json"))
+    cfg, counts = _qwen()
     t0, t1 = trace.window(tr)
     busy = trace.busy_ns(tr, t0, t1) * 1e-9
     window = {"decode_steps": 20, "occupancy_sum": 640, "engine_steps": 20,
@@ -79,7 +84,8 @@ def test_readers_on_the_recorded_trace(tr):
         config=cfg, counts=counts, window=window, monitored=True,
         engine_mode="slot",
         peaks=cell.peaks_for("TPU v5 lite", spec.BENCH_DIR),
-        decode_flops=cell._decode_tokens_flops(cfg, window["decode_log"]))
+        decode_flops=cell._decode_tokens_flops(counts, cfg,
+                                              window["decode_log"]))
     read = lambda name: spec.load_metric(name).read(ctx)
     assert read("batch_occupancy") == 32.0
     assert 50 < read("decode_step_ms") < 500
@@ -98,8 +104,7 @@ def test_split_metrics_read_as_their_base(tr, base):
     """A quantity split by the end-to-end metric it moves reads the same
     number under both names."""
     t0, t1 = trace.window(tr)
-    cfg = spec.load_json(os.path.join(spec.BENCH_DIR, "configs",
-                                      "qwen1.5-0.5b.json"))
+    cfg, counts = _qwen()
     window = {"engine_steps": 20, "accountant_s": 0.2,
               "decode_log": [(32, 32 * 600)] * 20, "prefills": [(0, 300)],
               "acct_rows": [300]}
@@ -108,7 +113,8 @@ def test_split_metrics_read_as_their_base(tr, base):
         busy_s=trace.busy_ns(tr, t0, t1) * 1e-9, config=cfg, counts=counts,
         window=window, monitored=True, engine_mode="slot",
         peaks=cell.peaks_for("TPU v5 lite", spec.BENCH_DIR),
-        decode_flops=cell._decode_tokens_flops(cfg, window["decode_log"]))
+        decode_flops=cell._decode_tokens_flops(counts, cfg,
+                                              window["decode_log"]))
     a = spec.load_metric(base).read(ctx)
     assert a is not None
     assert spec.load_metric(base + ".itl").read(ctx) == a

@@ -23,7 +23,6 @@ sys.path.insert(1, os.path.join(ROOT, "src"))
 import numpy as np  # noqa: E402
 
 from bench.harness import cell, correct, spec, system, weights  # noqa: E402
-from bench.reference import model as ref_model  # noqa: E402
 from repro.serve import ServeEngine  # noqa: E402
 
 
@@ -45,12 +44,13 @@ def witness(c, seed: int, seconds: float) -> dict:
     gc.unfreeze()
     gc.collect()              # the run's engine; its weights are kept
     cfg, params = c.config, kept["for_config"]
-    plain = weights.plain_weights(params)
+    plain = c.family.plain_weights(params, cfg)
     pad = int(cfg["engine"]["cache_len"])
 
     def gaps(prompt, served):
-        return ref_model.token_gaps(plain, cfg, list(prompt) + list(served),
-                                    len(prompt), pad)["served"]
+        return c.reference.token_gaps(plain, cfg,
+                                      list(prompt) + list(served),
+                                      len(prompt), pad)["served"]
     sample = kept["sample_tracks"]
     widest = {t.req.uid: gaps(t.req.prompt, t.req.generated) for t in sample}
     worst = max(sample, key=lambda t: widest[t.req.uid].max())
@@ -61,7 +61,7 @@ def witness(c, seed: int, seconds: float) -> dict:
         alone["engine"]["prefix_cache"] = False
     scfg = system.serve_config(alone, dict(c.traffic, power_monitor=False),
                                seed)
-    eng = ServeEngine(params, system.arch_config(cfg), scfg)
+    eng = ServeEngine(params, c.family.arch_config(cfg), scfg)
 
     def pick_alone(prompt, served, i):
         r1 = eng.submit(list(prompt) + list(served[:i]), max_new_tokens=1)
