@@ -1,0 +1,13 @@
+"""Expert layer: device time of the decode program's operations under
+the ``moe`` scope (router, the held experts' grouped matmuls, shared
+experts), per decode run. The weight casts XLA hoists out of the layer
+scan are in no scope and not in it (``_scopes``)."""
+from bench.metrics._scopes import per_decode_run
+
+
+def read(ctx):
+    got = per_decode_run(ctx, "moe")
+    if got is None:
+        return None
+    runs, sec = got
+    return 1e3 * sec / runs

@@ -1,8 +1,15 @@
 """DeepSeek-V2-Lite (16B total / 2.4B active) [arXiv:2405.04434]:
 MLA (kv_lora 512, no q-lora) + MoE with 64 routed experts top-6 and 2
 shared experts; the first layer uses a dense FFN (first_k_dense_replace=1).
+
+Routing as published: softmax over all 64 router logits, greedy top-6,
+the gates the raw probabilities of the six (``norm_topk_prob`` false,
+``routed_scaling_factor`` 1) -- what ``models/moe.py`` computes. Rope
+of the 64 rope dimensions is YaRN-scaled (``rope_scaling``: factor 40
+over 4096 original positions, beta 32 / 1, mscale = mscale_all_dim =
+0.707), which also multiplies the softmax scale by ``mscale ** 2``.
 """
-from repro.models.config import ArchConfig, MLAConfig, MoEConfig
+from repro.models.config import ArchConfig, MLAConfig, MoEConfig, YarnRope
 
 CONFIG = ArchConfig(
     name="deepseek-v2-lite-16b", family="moe",
@@ -17,6 +24,8 @@ CONFIG = ArchConfig(
                   num_shared=2, shared_ff=2816,
                   capacity_factor=1.25, group_size=512),
     rope_theta=10000.0,
+    yarn=YarnRope(factor=40.0, original_max_position=4096, beta_fast=32.0,
+                  beta_slow=1.0, mscale=0.707, mscale_all_dim=0.707),
 )
 
 SMOKE = CONFIG.with_(n_layers=3, d_model=64, n_heads=4, n_kv_heads=4,

@@ -268,17 +268,19 @@ class MLAConfig:
 
 
 def mla_attention(q_nope, q_rope, k_nope, k_rope, value, *, causal=True,
-                  block_k: int = 1024):
+                  scale=None, block_k: int = 1024):
     """MLA score path: per-head nope+rope concatenated queries/keys.
 
     q_nope/k_nope: [B,S,H,Dn]; q_rope: [B,S,H,Dr]; k_rope: [B,S,1,Dr]
-    (shared across heads); value: [B,S,H,Dv].
+    (shared across heads); value: [B,S,H,Dv]. ``scale`` defaults to
+    ``(Dn + Dr) ** -0.5``.
     """
     h = q_nope.shape[2]
     k_rope = jnp.broadcast_to(
         k_rope, k_rope.shape[:2] + (h, k_rope.shape[-1]))
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     k = jnp.concatenate([k_nope, k_rope], axis=-1)
-    scale = (q_nope.shape[-1] + q_rope.shape[-1]) ** -0.5
+    if scale is None:
+        scale = (q_nope.shape[-1] + q_rope.shape[-1]) ** -0.5
     return chunked_attention(q, k, value, causal=causal, scale=scale,
                              block_k=block_k)

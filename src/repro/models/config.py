@@ -16,6 +16,7 @@ import dataclasses
 from typing import Sequence
 
 from .attention import MLAConfig
+from .layers import YarnRope
 from .moe import MoEConfig
 from .recurrent import RGLRUConfig
 from .xlstm import XLSTMConfig
@@ -41,6 +42,7 @@ class ArchConfig:
     qkv_bias: bool = False
     pos: str = "rope"              # rope | mrope | sinusoidal
     rope_theta: float = 10000.0
+    yarn: YarnRope | None = None   # YaRN scaling of mla blocks' rope
     mrope_sections: tuple[int, ...] = ()
     tie_embeddings: bool = False
     logit_softcap: float = 0.0
@@ -88,4 +90,4 @@ class ArchConfig:
 
 
 __all__ = ["ArchConfig", "MLAConfig", "MoEConfig", "RGLRUConfig",
-           "XLSTMConfig"]
+           "XLSTMConfig", "YarnRope"]

@@ -12,6 +12,7 @@ a single parameter).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Callable, Sequence
 
 import jax
@@ -126,19 +127,76 @@ def rope_freqs(head_dim: int, theta: float = 10000.0) -> jax.Array:
                             / head_dim))
 
 
+@dataclasses.dataclass(frozen=True)
+class YarnRope:
+    """YaRN rotary scaling as DeepSeek-V2 publishes it (``rope_scaling``
+    of type ``yarn``): ``factor`` stretches the low frequencies, a linear
+    ramp between the correction dimensions of ``beta_fast`` and
+    ``beta_slow`` rotations over ``original_max_position`` positions
+    blends them with the unscaled ones, and the attention's softmax
+    scale gains ``yarn_mscale(factor, mscale_all_dim) ** 2``."""
+    factor: float = 1.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's magnitude correction ``0.1 * mscale * ln(factor) + 1``."""
+    if factor <= 1.0:
+        return 1.0
+    return 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, y: YarnRope) -> np.ndarray:
+    """YaRN inverse frequencies ``[dim // 2]`` (float32, host constants)."""
+    def correction_dim(rotations):
+        return (dim * math.log(y.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+    low = max(math.floor(correction_dim(y.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(y.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float32) - low)
+                   / (high - low), 0.0, 1.0)
+    extra_mask = 1.0 - ramp
+    freq_extra = 1.0 / (theta ** (np.arange(0, dim, 2, dtype=np.float32)
+                                  / dim))
+    freq_inter = freq_extra / y.factor
+    return (freq_inter * (1.0 - extra_mask)
+            + freq_extra * extra_mask).astype(np.float32)
+
+
+def yarn_cos_scale(y: YarnRope) -> float:
+    """The factor on YaRN's cos and sin tables."""
+    return (yarn_mscale(y.factor, y.mscale)
+            / yarn_mscale(y.factor, y.mscale_all_dim))
+
+
 def apply_rope(x: jax.Array, positions: jax.Array,
-               theta: float = 10000.0) -> jax.Array:
-    """Rotary embedding over the last dim.
+               theta: float = 10000.0,
+               yarn: YarnRope | None = None) -> jax.Array:
+    """Rotary embedding over the last dim (half-split pairs), YaRN-scaled
+    when ``yarn`` is given.
 
     Args:
       x: ``[..., S, H, D]`` (positions broadcast over H).
       positions: ``[..., S]`` integer positions.
     """
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                        # [D/2]
+    if yarn is None:
+        freqs, mag = rope_freqs(d, theta), 1.0
+    else:
+        freqs, mag = jnp.asarray(yarn_inv_freq(d, theta, yarn)), \
+            yarn_cos_scale(yarn)
     ang = positions[..., None].astype(jnp.float32) * freqs   # [..., S, D/2]
     cos = jnp.cos(ang)[..., None, :]                    # [..., S, 1, D/2]
     sin = jnp.sin(ang)[..., None, :]
+    if mag != 1.0:
+        cos, sin = cos * mag, sin * mag
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                           axis=-1)

@@ -263,15 +263,20 @@ def make_train_step(cfg: ArchConfig, optimizer, constrain: Constrain = _id,
 def pick_monitor_weights(params) -> list[tuple[str, jax.Array]]:
     """Representative layer-0 weights for power monitoring: the first
     input projection of the first block (whatever the mixer family calls
-    it), plus the FFN up projection when the block has one. The single
-    selection rule shared by train-step monitoring (:func:`_monitor_metrics`)
-    and the serving engine's per-request accountant -- so serving power
-    reports and training power metrics always watch the same sites."""
-    groups = params["stack"]["groups"]
-    if jax.tree.leaves(groups):
-        blk = jax.tree.map(lambda a: a[0], groups)["b0"]
+    it), plus the FFN up projection when the block has one. The first
+    block is the unrolled ``head`` block when the stack has one (e.g. a
+    leading dense layer before scanned expert layers), else the first
+    scanned block. The single selection rule shared by train-step
+    monitoring (:func:`_monitor_metrics`) and the serving engine's
+    per-request accountant -- so serving power reports and training
+    power metrics always watch the same sites."""
+    stack = params["stack"]
+    if stack["head"]:
+        blk = stack["head"][0]
+    elif jax.tree.leaves(stack["groups"]):
+        blk = jax.tree.map(lambda a: a[0], stack["groups"])["b0"]
     else:                                       # unrolled-only stacks
-        blk = (params["stack"]["head"] or params["stack"]["tail"])[0]
+        blk = stack["tail"][0]
     out = []
     mix = blk["mixer"]
     for wname in ("wq", "in_x", "up", "w_dkv"):
@@ -310,8 +315,11 @@ def make_decode_state(cfg: ArchConfig, batch: int, cache_len: int,
     hd]`` leaf fills half a 128-lane tile, so the compiler stores it
     T-minor and relays out each layer's slice to write a row; the flat
     layout is the one both the row write and the attention read use
-    (``transformer.apply_attention``). Other mixers' states keep their
-    own shapes. The dry-run turns this into ShapeDtypeStructs via
+    (``transformer.apply_attention``). Latent attention (``mla``) keeps
+    one row a position, ``[B, T, mla_row_width]``: the latent, the
+    64-wide rope key beside it, zeros to a whole number of lane tiles
+    (``transformer.apply_mla``). Other mixers' states keep their own
+    shapes. The dry-run turns this into ShapeDtypeStructs via
     ``jax.eval_shape``.
     """
     def block_state(spec: str):
@@ -326,10 +334,8 @@ def make_decode_state(cfg: ArchConfig, batch: int, cache_len: int,
                     jnp.zeros((batch, w, kv, hd), dtype),
                     jnp.full((batch, w), -1, jnp.int32))
         if mixer == "mla":
-            return (jnp.zeros((batch, cache_len, cfg.mla.kv_lora_rank),
-                              dtype),
-                    jnp.zeros((batch, cache_len, cfg.mla.qk_rope_head_dim),
-                              dtype))
+            return jnp.zeros((batch, cache_len, T.mla_row_width(cfg.mla)),
+                             dtype)
         if mixer == "rglru":
             w = cfg.rglru.lru_width or cfg.d_model
             return (jnp.zeros((batch, cfg.rglru.conv_width - 1, w), dtype),

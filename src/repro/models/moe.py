@@ -1,4 +1,5 @@
-"""Mixture-of-Experts with capacity-based top-k routing.
+"""Mixture-of-Experts: capacity-based top-k routing for training, dropless
+routing for serving.
 
 Mesh-TF-style einsum dispatch: tokens are grouped (group = a sequence chunk)
 and each group dispatches into per-expert capacity buffers via one-hot
@@ -10,6 +11,14 @@ Paper tie-in: tokens dropped by capacity overflow produce *all-zero rows* in
 the dispatched expert inputs -- exactly the zero streams the paper's
 zero-value gating exploits (measured by the PowerMonitor, and skippable by
 the ``zvg_matmul`` kernel at tile granularity on TPU).
+
+Serving (prefill and decode) routes dropless instead: every (token,
+expert) pair the router picks is computed, whatever the load, so a
+prompt's tokens never depend on which other tokens share its group. An
+expert layer may hold only a contiguous share of the experts
+(``MoEConfig.first_expert`` / ``held``: one chip's share of an
+expert-parallel deployment); it still routes over all of them and
+computes only its own experts' part of each token's output.
 """
 from __future__ import annotations
 
@@ -31,13 +40,19 @@ class MoEConfig:
     capacity_factor: float = 1.25
     group_size: int = 512           # tokens per dispatch group
     router_noise: float = 0.0
+    first_expert: int = 0           # the experts this layer holds:
+    held: int = 0                   # [first, first + held); 0 = all
+
+    @property
+    def n_held(self) -> int:
+        return self.held or self.num_experts
 
 
 def make_moe(key, d: int, cfg: MoEConfig) -> dict:
     ks = jax.random.split(key, 5)
-    e, f = cfg.num_experts, cfg.expert_ff
+    e, f = cfg.n_held, cfg.expert_ff
     p = {
-        "router": L.dense_param(ks[0], d, e, "embed", None),
+        "router": L.dense_param(ks[0], d, cfg.num_experts, "embed", None),
         "w_gate": L.Param(
             L.normal_init(ks[1], (e, d, f), d ** -0.5),
             ("expert", "embed", "ff")),
@@ -114,16 +129,74 @@ def _ep_constrain(t: jax.Array, expert_dim: int) -> jax.Array:
     return t
 
 
-def apply_moe(p: dict, x: jax.Array, cfg: MoEConfig, act: str = "silu"):
-    """MoE layer: ``x [B, S, D] -> (y [B, S, D], aux_loss)``."""
+def _router_logits(p: dict, x: jax.Array) -> jax.Array:
+    """Every expert's router logit, in float32."""
+    return (x @ p["router"].value.astype(jnp.float32)
+            if x.dtype == jnp.float32
+            else x.astype(jnp.float32) @ p["router"].value)
+
+
+def _held_experts(p: dict, x: jax.Array, probs: jax.Array, cfg: MoEConfig,
+                  actf) -> jax.Array:
+    """Dropless top-k over all experts, computed for the held ones only.
+
+    ``x [N, D]``, ``probs [N, E]``. The ``N * top_k`` (token, expert)
+    pairs are sorted by held expert, with the pairs of experts this
+    layer does not hold after them, and the three expert products run as
+    grouped matmuls (``lax.ragged_dot``) over the held groups. Rows past
+    the held groups are not computed, and what they hold is not defined
+    (the TPU leaves whatever its buffer held, NaN included), so they are
+    selected away, not multiplied by a zero gate. Each pair's output is
+    weighted by its router probability (not renormalised over the top k)
+    and summed into its token in float32."""
+    n, d = x.shape
+    k, held = cfg.top_k, cfg.n_held
+    gates, experts = jax.lax.top_k(probs, k)                 # [N, k]
+    local = experts - cfg.first_expert
+    mine = (local >= 0) & (local < held)
+    group = jnp.where(mine, local, held).reshape(-1)         # [N * k]
+    order = jnp.argsort(group, stable=True)
+    token = order // k
+    sizes = jnp.sum(group[:, None] == jnp.arange(held)[None, :], axis=0,
+                    dtype=jnp.int32)
+    xs = x[token]
+    wv = lambda name: p[name].value.astype(x.dtype)
+    h = actf(jax.lax.ragged_dot(xs, wv("w_gate"), sizes)) \
+        * jax.lax.ragged_dot(xs, wv("w_up"), sizes)
+    y = jax.lax.ragged_dot(h, wv("w_down"), sizes)
+    gate = gates.reshape(-1)[order]
+    computed = jnp.arange(n * k) < jnp.sum(sizes)
+    out = jnp.zeros((n, d), jnp.float32).at[token].add(jnp.where(
+        computed[:, None], y.astype(jnp.float32) * gate[:, None], 0.0))
+    return out.astype(x.dtype)
+
+
+def apply_moe(p: dict, x: jax.Array, cfg: MoEConfig, act: str = "silu",
+              dropless: bool = False):
+    """MoE layer: ``x [B, S, D] -> (y [B, S, D], aux_loss)``. Training
+    routes with capacity buffers; ``dropless`` (the serving paths) routes
+    every pick (``_held_experts``), with a zero aux loss."""
     b, s, d = x.shape
+    actf = {"silu": jax.nn.silu, "gelu": jax.nn.gelu}[act]
+    if dropless:
+        x2 = x.reshape(b * s, d)
+        with jax.named_scope("router"):
+            probs = jax.nn.softmax(_router_logits(p, x2), axis=-1)
+        with jax.named_scope("experts"):
+            out = _held_experts(p, x2, probs, cfg, actf).reshape(b, s, d)
+        if "shared" in p:
+            with jax.named_scope("shared"):
+                out = out + L.apply_mlp(p["shared"], x, act)
+        return out, jnp.zeros((), jnp.float32)
+    if cfg.n_held != cfg.num_experts:
+        raise ValueError(
+            f"capacity routing needs every expert; this layer holds "
+            f"{cfg.n_held} of {cfg.num_experts} (dropless serving only)")
     gs = min(cfg.group_size, s)
     assert s % gs == 0, (s, gs)
     ng = s // gs
     xg = x.reshape(b * ng, gs, d)
-    logits = (xg @ p["router"].value.astype(jnp.float32)
-              if xg.dtype == jnp.float32
-              else xg.astype(jnp.float32) @ p["router"].value)
+    logits = _router_logits(p, xg)
     capacity = max(int(gs * cfg.top_k * cfg.capacity_factor
                        / cfg.num_experts), 1)
     dispatch, combine, aux = _topk_dispatch(logits, cfg.top_k, capacity)
@@ -135,7 +208,6 @@ def apply_moe(p: dict, x: jax.Array, cfg: MoEConfig, act: str = "silu"):
     # EP all-to-all (group-gather/expert-scatter) instead of replicating
     xin = jnp.einsum("gsec,gsd->gecd", dispatch, xg)
     xin = _ep_constrain(xin, 1)
-    actf = {"silu": jax.nn.silu, "gelu": jax.nn.gelu}[act]
     h = actf(jnp.einsum("gecd,edf->gecf", xin,
                         p["w_gate"].value.astype(x.dtype))) \
         * jnp.einsum("gecd,edf->gecf", xin, p["w_up"].value.astype(x.dtype))

@@ -324,12 +324,37 @@ def _ring_decode(q, kc, vc, slots, bpos, cfg, scale):
 
 
 # --------------------------------------------------------------------- MLA
+def mla_row_width(mla) -> int:
+    """Words of one position of the latent decode cache: the latent and
+    the rope key, padded up to whole 128-lane tiles."""
+    return -(-(mla.kv_lora_rank + mla.qk_rope_head_dim) // 128) * 128
+
+
 def apply_mla(p, x, cfg: ArchConfig, *, positions, state=None,
-              prefill=False, cache_len=0, pages=None):
+              prefill=False, cache_len=0, pages=None, layer=None):
+    """Multi-head latent attention. The decode cache holds, per position,
+    the normed latent and the shared rope key side by side in one row,
+    zero-padded to whole 128-lane tiles: ``[B, T, mla_row_width]`` (paged
+    pools ``[P, ps, mla_row_width]``). The TPU stores an array whose
+    minor dim is not a multiple of 128 lanes position-minor, so a 64-wide
+    rope-key leaf (or a 576-wide row) would be relaid out, a whole layer
+    in and out, for every step's row write; a 640-wide row is stored as
+    written, and one product over it gives both score terms. With
+    ``layer`` (a traced index) the cache is the whole stacked ``[G,
+    ...]`` carry of the layer scan: the step's rows are scattered into it
+    at ``[layer, row, position]`` and the absorbed attention reads the
+    layer's slice inside its products, as ``apply_attention`` does for
+    full attention. Rope is YaRN-scaled when ``cfg.yarn`` is set, and the
+    softmax scale with it."""
     mla = cfg.mla
     b, s, d = x.shape
     h = cfg.n_heads
+    r = mla.kv_lora_rank
     dn, dr, dv = mla.qk_nope_head_dim, mla.qk_rope_head_dim, mla.v_head_dim
+    scale = (dn + dr) ** -0.5
+    if cfg.yarn is not None:
+        scale *= L.yarn_mscale(cfg.yarn.factor, cfg.yarn.mscale_all_dim) ** 2
+    rope = lambda t: L.apply_rope(t, positions, cfg.rope_theta, cfg.yarn)
 
     if mla.q_lora_rank:
         cq = L.apply_norm("rms", p["q_norm"],
@@ -337,64 +362,64 @@ def apply_mla(p, x, cfg: ArchConfig, *, positions, state=None,
         q = jnp.einsum("bsr,rhe->bshe", cq, p["w_uq"].value.astype(x.dtype))
     else:
         q = jnp.einsum("bsd,dhe->bshe", x, p["wq"].value.astype(x.dtype))
-    q_nope, q_rope = q[..., :dn], q[..., dn:]
-    q_rope = L.apply_rope(q_rope, positions, cfg.rope_theta)
+    q_nope, q_rope = q[..., :dn], rope(q[..., dn:])
 
     ckv = L.apply_norm("rms", p["kv_norm"],
                        mm.matmul(x, p["w_dkv"].value.astype(x.dtype)))
-    kr = mm.matmul(x, p["w_kr"].value.astype(x.dtype))[:, :, None, :]
-    kr = L.apply_rope(kr, positions, cfg.rope_theta)
+    kr = rope(mm.matmul(x, p["w_kr"].value.astype(x.dtype))[:, :, None, :])
+    pad = [(0, 0), (0, 0), (0, mla_row_width(mla) - r - dr)]
+    rows = jnp.pad(jnp.concatenate([ckv, kr[:, :, 0]], axis=-1), pad)
 
     if state is not None:                       # ---- absorbed decode
+        cache = state
         if pages is not None:                   # paged: pool-backed cache
-            ckv_p, kr_p = state                 # [P, ps, r], [P, ps, dr]
-            pg, off = _page_targets(pages, positions, ckv_p.shape[1])
-            new_state = (ckv_p.at[pg, off].set(ckv.astype(ckv_p.dtype)),
-                         kr_p.at[pg, off].set(kr[:, :, 0].astype(kr_p.dtype)))
-            ckv_c = _gather_pages(new_state[0], pages)
-            kr_c = _gather_pages(new_state[1], pages)
-            kpos = jnp.arange(ckv_c.shape[1])
+            at = _page_targets(pages, positions, cache.shape[-2])
+            kpos = jnp.arange(pages.shape[1] * cache.shape[-2])
             # per-query causal mask [B, S, T]; for S == 1 this broadcasts
             # to exactly the dense decode mask below (bit-identity)
             mask = (kpos[None, None, :]
                     <= positions.astype(jnp.int32)[:, :, None])[:, None]
         else:
-            ckv_c, kr_c = state
             bpos = _decode_batch_pos(cfg, positions)
-            rows = jnp.arange(b)
-            ckv_c = ckv_c.at[rows, bpos].set(ckv[:, 0].astype(ckv_c.dtype))
-            kr_c = kr_c.at[rows, bpos].set(kr[:, 0, 0].astype(kr_c.dtype))
-            new_state = (ckv_c, kr_c)
-            kpos = jnp.arange(ckv_c.shape[1])
+            at = (jnp.arange(b)[:, None], bpos[:, None])
+            kpos = jnp.arange(cache.shape[-2])
             mask = (kpos[None, :] <= bpos[:, None])[:, None, None, :]
+        if layer is not None:
+            at = (layer,) + at
+        cache = cache.at[at].set(rows.astype(cache.dtype))
+        seen = (_gather_pages(cache, pages, layer) if pages is not None
+                else _layer_of(cache, layer))          # [B, T, row]
         q_eff = jnp.einsum("bshe,rhe->bshr", q_nope,
                            p["w_uk"].value.astype(x.dtype))
         # keep the absorbed query latent-sharded like the cache so the
         # score contraction is partial-sum (no cache all-gather)
         q_eff = A._try_constrain(q_eff, (None, None, None, "model"))
-        s_nope = jnp.einsum("bshr,btr->bhst", q_eff, ckv_c)
-        s_rope = jnp.einsum("bshe,bte->bhst", q_rope, kr_c)
-        scores = (s_nope + s_rope).astype(jnp.float32) * ((dn + dr) ** -0.5)
-        scores = jnp.where(mask, scores, A.NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        lat = jnp.einsum("bhst,btr->bshr", probs, ckv_c)
+        q_row = jnp.pad(jnp.concatenate(
+            [q_eff, q_rope.astype(q_eff.dtype)], -1), [(0, 0)] + pad)
+        with jax.named_scope("latent"):
+            scores = jnp.einsum("bshr,btr->bhst", q_row, seen,
+                                preferred_element_type=jnp.float32) * scale
+            scores = jnp.where(mask, scores, A.NEG_INF)
+            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+            # the whole row, rope and pad lanes too, so that no
+            # latent-only slice of the cache is made; their products
+            # are dropped
+            lat = jnp.einsum("bhst,btr->bshr", probs, seen)[..., :r]
         out = jnp.einsum("bshr,rhe->bshe", lat,
                          p["w_uv"].value.astype(x.dtype))
         out = mm.matmul(out.reshape(b, s, h * dv),
                         p["wo"].value.astype(x.dtype))
-        return out, new_state
+        return out, cache
 
     # ---- parallel: expand per-head keys/values
     k_nope = jnp.einsum("bsr,rhe->bshe", ckv, p["w_uk"].value.astype(x.dtype))
     value = jnp.einsum("bsr,rhe->bshe", ckv, p["w_uv"].value.astype(x.dtype))
-    out = A.mla_attention(q_nope, q_rope, k_nope, kr, value,
+    out = A.mla_attention(q_nope, q_rope, k_nope, kr, value, scale=scale,
                           block_k=cfg.attn_block_k)
     out = out.reshape(b, s, h * dv) @ p["wo"].value.astype(x.dtype)
     new_state = None
     if prefill:
-        pad = cache_len - s
-        new_state = (jnp.pad(ckv, ((0, 0), (0, pad), (0, 0))),
-                     jnp.pad(kr[:, :, 0], ((0, 0), (0, pad), (0, 0))))
+        new_state = jnp.pad(rows, ((0, 0), (0, cache_len - s), (0, 0)))
     return out, new_state
 
 
@@ -413,7 +438,8 @@ def apply_mixer(p, x, cfg: ArchConfig, mixer: str, *, positions,
                                pages=pages, layer=layer)
     if mixer == "mla":
         return apply_mla(p, x, cfg, positions=positions, state=state,
-                         prefill=prefill, cache_len=cache_len, pages=pages)
+                         prefill=prefill, cache_len=cache_len, pages=pages,
+                         layer=layer)
     if mixer == "rglru":
         return R.apply_recurrent_block(p, x, state, want_state=prefill)
     if mixer == "mlstm":
@@ -517,7 +543,9 @@ def apply_block(p, x, cfg: ArchConfig, spec: str, *, positions,
         h = L.apply_norm(cfg.norm, p["norm2"], x)
         with jax.named_scope(ffn):
             if ffn == "moe":
-                y, aux = M.apply_moe(p["ffn"], h, cfg.moe, cfg.act)
+                # serving (prefill, decode) routes dropless
+                y, aux = M.apply_moe(p["ffn"], h, cfg.moe, cfg.act,
+                                     dropless=prefill or state is not None)
             else:
                 y = L.apply_mlp(p["ffn"], h, cfg.act)
         x = constrain(x + cfg.resid_mult * y)
@@ -527,9 +555,10 @@ def apply_block(p, x, cfg: ArchConfig, spec: str, *, positions,
 # -------------------------------------------------------------------- stack
 def _writes_in_place(spec: str) -> bool:
     """Whether a scanned block's decode cache is written in place in the
-    stacked ``[G, ...]`` carry (full attention, see ``apply_attention``)
-    instead of being sliced out and written back."""
-    return parse_spec(spec)[0] == "attn"
+    stacked ``[G, ...]`` carry (full attention and latent attention, see
+    ``apply_attention`` and ``apply_mla``) instead of being sliced out
+    and written back."""
+    return parse_spec(spec)[0] in ("attn", "mla")
 
 
 def make_stack(key, cfg: ArchConfig) -> dict:
@@ -594,12 +623,13 @@ def apply_stack(params, x, cfg: ArchConfig, *, positions, states=None,
         if decode:
             # keep the stacked per-layer states in the scan CARRY, so the
             # (large) KV caches never pass through the scan's xs/ys double
-            # buffers. Full-attention blocks take the whole stacked cache
-            # and the layer index and scatter their rows into it in place
-            # (apply_attention): a sliced-out layer of a cache whose minor
-            # dim is hd = 64, half a 128-lane tile, cost a relayout copy
-            # in and out plus a whole-slice write-back every step. Other
-            # states are sliced out and written back in place.
+            # buffers. Full- and latent-attention blocks take the whole
+            # stacked cache and the layer index and scatter their rows
+            # into it in place (apply_attention, apply_mla): a sliced-out
+            # layer of a cache whose minor dim is 64, half a 128-lane
+            # tile, cost a relayout copy in and out plus a whole-slice
+            # write-back every step. Other states are sliced out and
+            # written back in place.
             stacked = {f"b{i}" for i, spec in enumerate(cfg.pattern)
                        if _writes_in_place(spec)}
 

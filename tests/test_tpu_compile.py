@@ -9,16 +9,18 @@ can, at no chip time. Nothing runs, so results are checked elsewhere
 (``tests/test_power_counter_kernels.py`` in interpret mode, and
 ``chip_smoke.py`` on the chip).
 
-The decode step is compiled here too, the way the serving engine jits
-it, to check the layout of its KV cache in the optimized program: what
-the TPU compiler does with a cache layout shows only in a compile for a
-TPU.
+The decode steps of qwen1.5-0.5b and of DeepSeek-V2-Lite's benchmark
+share are compiled here too, the way the serving engine jits them, to
+check the layout of their caches (full-attention K/V, the latent rows)
+in the optimized program: what the TPU compiler does with a cache
+layout shows only in a compile for a TPU.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
 
+import dataclasses
 import functools
 import re
 
@@ -27,6 +29,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs.deepseek_v2_lite import CONFIG as DSV2
 from repro.configs.qwen1_5_0_5b import CONFIG as QWEN
 from repro.core import bic, monitor
 from repro.design.evaluate import menu_args
@@ -135,15 +138,10 @@ def _big_moves(hlo: str, min_elems: int) -> list[str]:
     return out
 
 
-def test_decode_step_keeps_kv_cache_in_place(one_chip):
-    """qwen1.5-0.5b's decode step at full width (two layers), jitted as
-    the serving engine jits it (the ``ref`` backend bound by
-    ``functools.partial``, the cache donated), neither copies a layer's
-    K or V cache nor writes a whole layer slice back into the stack: the
-    step's rows are scattered into the stacked cache in place and the
-    attention reads the layer's slice inside its products."""
-    cfg = QWEN.with_(n_layers=2)
-
+def _decode_hlo(cfg, one_chip, slots: int, horizon: int) -> str:
+    """The optimized program of ``cfg``'s decode step, jitted as the
+    serving engine jits it (the ``ref`` backend bound by
+    ``functools.partial``, the cache donated), compiled for one v5e."""
     def spec(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=one_chip), tree)
@@ -151,12 +149,40 @@ def test_decode_step_keeps_kv_cache_in_place(one_chip):
     params = spec(jax.eval_shape(
         lambda: lm.init_model(jax.random.key(0), cfg)))
     states = spec(jax.eval_shape(
-        lambda: lm.make_decode_state(cfg, SLOTS, HORIZON)))
-    col = jax.ShapeDtypeStruct((SLOTS, 1), jnp.int32, sharding=one_chip)
+        lambda: lm.make_decode_state(cfg, slots, horizon)))
+    col = jax.ShapeDtypeStruct((slots, 1), jnp.int32, sharding=one_chip)
     step = jax.jit(functools.partial(mm.with_backend, "ref",
                                      lm.make_decode_step(cfg)),
                    donate_argnums=(1,))
-    hlo = step.lower(params, states,
-                     {"tokens": col, "positions": col}).compile().as_text()
+    return step.lower(params, states,
+                      {"tokens": col, "positions": col}).compile().as_text()
+
+
+def test_decode_step_keeps_kv_cache_in_place(one_chip):
+    """qwen1.5-0.5b's decode step at full width (two layers) neither
+    copies a layer's K or V cache nor writes a whole layer slice back
+    into the stack: the step's rows are scattered into the stacked cache
+    in place and the attention reads the layer's slice inside its
+    products."""
+    cfg = QWEN.with_(n_layers=2)
+    hlo = _decode_hlo(cfg, one_chip, SLOTS, HORIZON)
     layer = SLOTS * HORIZON * cfg.n_kv_heads * cfg.hd
     assert _big_moves(hlo, layer) == []
+
+
+#: the DeepSeek-V2-Lite benchmark configuration's slot cache and share:
+#: 64 slots of 4096 positions, 8 of the 64 experts, 1/8 of the vocabulary
+DS_SLOTS, DS_HORIZON = 64, 4096
+
+
+def test_decode_step_keeps_latent_cache_in_place(one_chip):
+    """DeepSeek-V2-Lite's decode step at full width (the dense layer 0
+    and two scanned expert layers, 8 held experts) moves no whole layer
+    of either latent-cache leaf: neither the 512-wide latent nor the
+    64-wide rope key, the half-lane-tile shape that cost full attention
+    its relayout copies, is copied or written back per layer."""
+    cfg = DSV2.with_(n_layers=3, vocab=12800,
+                     moe=dataclasses.replace(DSV2.moe, held=8))
+    hlo = _decode_hlo(cfg, one_chip, DS_SLOTS, DS_HORIZON)
+    rope_layer = DS_SLOTS * DS_HORIZON * cfg.mla.qk_rope_head_dim
+    assert _big_moves(hlo, rope_layer) == []
